@@ -28,6 +28,7 @@ from pathlib import Path
 import numpy as np
 
 import repro  # noqa: F401
+from repro.compile_cache import use_compile_cache
 from repro.core import btree, pgm, radix_spline, rmi, rmrt
 
 from . import harness
@@ -247,6 +248,7 @@ def main() -> None:
     ap.add_argument("--out", default=str(Path(__file__).resolve().parent.parent
                                          / "BENCH_lookup.json"))
     args = ap.parse_args()
+    use_compile_cache()
     if args.distributed_worker:
         rows = bench_distributed(max(args.sizes), args.distributed_worker)
         print(json.dumps(rows))

@@ -189,6 +189,8 @@ def main() -> None:
     ap.add_argument("--serve-worker", type=int, default=None,
                     help=argparse.SUPPRESS)
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.serve_worker is not None:
         # forced-device-count subprocess (harness.worker_suite protocol):

@@ -57,6 +57,7 @@ from pathlib import Path
 import numpy as np
 
 import repro  # noqa: F401
+from repro.compile_cache import use_compile_cache
 
 Q = 8_192
 REPEATS = 3
@@ -625,6 +626,7 @@ def main() -> None:
     ap.add_argument("--out", default=str(Path(__file__).resolve().parent.parent
                                          / "BENCH_updates.json"))
     args = ap.parse_args()
+    use_compile_cache()
     if args.sharded_worker:
         rows = bench_sharded(args.n, args.sharded_worker)
         print(json.dumps(rows))

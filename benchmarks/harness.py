@@ -69,8 +69,14 @@ def worker_rows(module: str, flag: str, n_devices: int, argv: list,
     <n_devices> <argv...>`` with XLA_FLAGS forcing the count; the worker
     prints its rows as JSON on the last stdout line.  Shared by the
     distributed rows of bench_lookup and the sharded rows of
-    bench_updates.  Returns [] (with the worker's stderr echoed) on any
-    failure, so a broken mesh bench never sinks the host run."""
+    bench_updates.  Raises ``RuntimeError`` when the worker fails, times
+    out or prints no parseable rows, so a broken phase fails the run.
+    Refuses to re-exec from a parent whose backend is the TPU: the parent
+    holds the chip, and a child that needs it would fail or hang."""
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{module} {flag}: forced-device-count workers run on the CPU "
+            "backend only; this process holds the TPU")
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count"
                          f"={n_devices}")
@@ -79,19 +85,17 @@ def worker_rows(module: str, flag: str, n_devices: int, argv: list,
             [sys.executable, "-m", module, flag, str(n_devices),
              *map(str, argv)],
             env=env, capture_output=True, text=True, timeout=timeout)
-    except subprocess.TimeoutExpired:
-        print(f"{module} worker timed out after {timeout}s", file=sys.stderr)
-        return []
+    except subprocess.TimeoutExpired as e:
+        raise RuntimeError(
+            f"{module} worker timed out after {timeout}s") from e
     if proc.returncode != 0:
-        print(f"{module} worker failed:\n{proc.stderr[-2000:]}",
-              file=sys.stderr)
-        return []
+        raise RuntimeError(f"{module} worker failed (exit "
+                           f"{proc.returncode}):\n{proc.stderr[-2000:]}")
     try:
         return json.loads(proc.stdout.splitlines()[-1])
-    except (json.JSONDecodeError, IndexError):
-        print(f"{module} worker emitted no parseable rows:\n"
-              f"{proc.stdout[-2000:]}", file=sys.stderr)
-        return []
+    except (json.JSONDecodeError, IndexError) as e:
+        raise RuntimeError(f"{module} worker emitted no parseable rows:\n"
+                           f"{proc.stdout[-2000:]}") from e
 
 
 def worker_suite(module: str, flag: str, n_devices: int, n: int,

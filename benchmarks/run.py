@@ -111,6 +111,8 @@ def main() -> None:
                          "BENCH_*.json trajectories (keyed by git sha + "
                          "suite)")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     only = set(args.only.split(",")) if args.only else set(SUITES)
 
     by_suite: dict[str, list] = {}

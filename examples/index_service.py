@@ -23,9 +23,12 @@ import jax
 import jax.numpy as jnp
 
 import repro  # noqa: F401
+from repro.compile_cache import use_compile_cache
 from repro.api import Index
 from repro.data.indexed_dataset import IndexedDataset
 from repro.serve.frontend import BatchingFrontend, Request, ServeConfig
+
+use_compile_cache()
 
 mesh = jax.make_mesh((4,), ("data",))
 rng = np.random.default_rng(3)

@@ -13,9 +13,12 @@ import numpy as np
 import jax.numpy as jnp
 
 import repro  # noqa: F401
+from repro.compile_cache import use_compile_cache
 from repro.api import Index
 from repro.core import reuse, rmi, rmrt, synth
 from repro.kernels import ops
+
+use_compile_cache()
 
 EPS = 0.9
 

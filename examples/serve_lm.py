@@ -10,11 +10,14 @@ import jax
 import jax.numpy as jnp
 
 import repro  # noqa: F401
+from repro.compile_cache import use_compile_cache
 from repro.configs.reduced import reduced
 from repro.launch.mesh import make_smoke_mesh
 from repro.models import model as M
 from repro.serve import step as serve_step
 from repro.serve.kvcache import PagedKVCache, learned_page_table
+
+use_compile_cache()
 
 ARCH = "qwen3-4b"   # reduced variant: qk_norm + GQA family
 B, S_PRE, N_NEW, S_MAX = 4, 48, 16, 128

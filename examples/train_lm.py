@@ -10,7 +10,10 @@ import os
 import tempfile
 
 import repro  # noqa: F401
+from repro.compile_cache import use_compile_cache
 from repro.launch.train import train
+
+use_compile_cache()
 
 
 def main():

@@ -17,8 +17,4 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
-# Installs the jax 0.4.x compat shims (jax.shard_map, AxisType, pcast, ...)
-# as an import side effect; must run before any repro module traces.
-from .models import sharding as _jax_compat  # noqa: E402,F401
-
 __version__ = "1.0.0"

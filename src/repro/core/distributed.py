@@ -43,10 +43,15 @@ costs O(touched shards), never O(all shards):
     (``kernels.lookup.capacity_class`` — pow2, 128 floor); the assembled
     stack pads every slice to the *global* max class with +inf keys / zero
     tombstones / edge-extended prefix sums.
+  * Each shard's own tiers live on its home device, the first device of
+    its slot on the mesh axis (``_shard_device``); its mutations run
+    there.  Stacked tier arrays are assembled per device from the rows
+    that device holds, so no device stages the whole stack.
   * A mutation (routed merge, delete, rebuild, migration) marks only the
     touched shards dirty; the next ``find`` rewrites exactly those rows of
-    the stacked arrays (one batched row-scatter per array) and leaves the
-    rest untouched.  Packed kernel tables ride the same rows: per-shard
+    the stacked arrays (the dirty devices' buffers; the root and leaf
+    models take one batched row-scatter) and leaves the rest untouched.
+    Packed kernel tables ride the same rows: per-shard
     ``mat``/``vec`` come from the shard's cached ``RMIIndex.packed_tables``
     and the root block from ``DynamicRMI.packed_root`` (cacheable forever —
     roots and routing scales are frozen at shard build).
@@ -87,7 +92,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from . import drift as drift_mod
@@ -243,7 +248,7 @@ def make_lookup_fn(index: ShardedIndex, *,
     caller) — EXPERIMENTS.md §Perf index-service iteration.
 
     ``path`` routes the per-shard answer through the fused Pallas kernel
-    (``kernels.ops.index_lookup``: in-kernel routing + clamped tiled
+    (``kernels.ops.index_lookup``: routing + clamped tiled
     search + sparse seam verification) or the clamped jnp path — the
     shared :func:`core.paths.resolve_path` contract (``"auto"`` = kernel
     on TPU backends when every shard's keys are f32-exact).
@@ -320,15 +325,44 @@ def make_lookup_fn(index: ShardedIndex, *,
         return ranks
 
     fn = jax.shard_map(
-        shard_fn, mesh=mesh,
+        shard_fn, mesh=_auto_axes(mesh),
         in_specs=(P(), P(axis), P(axis), P(axis), P(axis)),
-        out_specs=P(axis), check_vma=True)
+        out_specs=P(axis),
+        check_vma=_checks_vma(use_kernel, interpret))
 
     @jax.jit
     def lookup(q_global: Array) -> Array:
         return fn(index.splits, index.keys, index.valid, tables, q_global)
 
     return lookup
+
+
+def _auto_axes(mesh: Mesh) -> Mesh:
+    """The mesh every ``shard_map`` program here runs on: ``mesh`` with all
+    axes typed Auto.  ``jax.make_mesh`` types its axes Explicit, and an
+    array sharded over an Explicit axis refuses the plain indexing that
+    this module and its callers apply to results (``found[:Q]``)."""
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(mesh.axis_names))
+
+
+def _shard_device(mesh: Mesh, axis: str, s: int):
+    """Home device of shard ``s``'s own tiers: the first device of its
+    slot along ``axis``."""
+    ax = mesh.axis_names.index(axis)
+    return np.moveaxis(mesh.devices, ax, 0)[s:s + 1].flat[0]
+
+
+def _checks_vma(use_kernel: bool, interpret: bool | None) -> bool:
+    """``check_vma`` for a ``shard_map`` program here: on, except around
+    an *interpreted* Pallas kernel.  The Pallas interpreter evaluates the
+    kernel body under the check, with invariant grid indices and literals
+    next to varying blocks, which no kernel passes.  Compiled kernels carry
+    their operands' varying axes (``kernels.call.pallas_call``)."""
+    if not use_kernel:
+        return True
+    from ..kernels.ops import _default_interpret
+    return not (_default_interpret() if interpret is None else interpret)
 
 
 def _cumcount(ids: Array, n_bins: int) -> Array:
@@ -391,8 +425,8 @@ def _row_scatter_jit(dst: Array, idx: Array, rows: Array) -> Array:
 
 def scatter_rows_donated(dst: Array, idx: Array, rows: Array) -> Array:
     """Batched row scatter ``dst[idx] = rows`` with the destination buffer
-    *donated*: the restack slice cache (and the serve front-end's tenant
-    stack riding it) rewrites dirty rows truly in place instead of
+    *donated*: the restack slice cache's model rows and the serve
+    front-end's tenant stack rewrite dirty rows truly in place instead of
     allocating a copy of the whole stacked array per mutation.  The caller
     must drop its handle to ``dst`` (it is invalidated by donation) and
     keep only the returned array.
@@ -418,7 +452,8 @@ def _offs_jit(counts: Array) -> Array:
     """Per-shard global live-rank offsets from the device counter table:
     offs[s] = live keys in shards < s (one cumsum — no host scan)."""
     live = counts[:, 0] - counts[:, 1] + counts[:, 2]
-    return (jnp.cumsum(live) - live).astype(jnp.int32)
+    live = live.astype(jnp.int32)
+    return jnp.cumsum(live, dtype=jnp.int32) - live
 
 
 @jax.jit
@@ -533,12 +568,15 @@ class ShardedDynamicIndex:
             raise ValueError(
                 "ShardedDynamicIndex requires a monotone (linear) root: "
                 "split routing and run snapping assume key order")
-        kn = np.asarray(jnp.asarray(keys, jnp.float64))
+        kn = np.asarray(keys, np.float64)
         n_shards = mesh.shape[axis]
         bounds = shard_bounds(kn, n_shards)
-        shards = [DynamicRMI.build(
-            jnp.asarray(kn[bounds[s]:bounds[s + 1]]), pool=pool, eps=eps,
-            n_leaves=n_leaves, **rmi_kwargs) for s in range(n_shards)]
+        shards = []
+        for s in range(n_shards):
+            with jax.default_device(_shard_device(mesh, axis, s)):
+                shards.append(DynamicRMI.build(
+                    kn[bounds[s]:bounds[s + 1]], pool=pool, eps=eps,
+                    n_leaves=n_leaves, **rmi_kwargs))
         idx = cls(mesh=mesh, axis=axis,
                   splits=_splits_from_bounds(kn, bounds), shards=shards,
                   eps=eps, n_leaves=n_leaves, pool=pool,
@@ -578,7 +616,9 @@ class ShardedDynamicIndex:
             # no shrinkable state survives a mutation, so a cold restack is
             # always a pure re-assembly of the logical state — the warm/cold
             # bit-exactness contract the restack-cache tests pin.
-            if d.shrink_capacity():
+            with self._on(s):
+                shrank = d.shrink_capacity()
+            if shrank:
                 self.capacity_shrinks += 1
             self._bcaps[s] = d.index.keys.shape[0]
             self._dcaps[s] = d.delta_keys.shape[0]
@@ -598,6 +638,18 @@ class ShardedDynamicIndex:
     @property
     def n_shards(self) -> int:
         return len(self.shards)
+
+    def _on(self, s: int):
+        """Context for work on shard ``s``: it runs, and the arrays it
+        makes land, on the shard's home device (:func:`_shard_device`)."""
+        return jax.default_device(_shard_device(self.mesh, self.axis, s))
+
+    def shard_sharding(self, lead: int = 0) -> NamedSharding:
+        """Placement of stacked per-shard state: the shard axis (after
+        ``lead`` unsharded leading axes) split over the mesh, so each
+        device holds only its own shards' slices."""
+        return NamedSharding(_auto_axes(self.mesh),
+                             P(*(None,) * lead, self.axis))
 
     @property
     def f32_exact(self) -> bool:
@@ -632,7 +684,8 @@ class ShardedDynamicIndex:
         dest = self._route(keys)
         touched = np.unique(dest)
         for s in touched:
-            self.shards[s].insert_batch(keys[dest == s])
+            with self._on(s):
+                self.shards[s].insert_batch(keys[dest == s])
         self._touch(touched)
         self._maybe_rebalance()
 
@@ -645,7 +698,8 @@ class ShardedDynamicIndex:
         dest = self._route(keys)
         touched = np.unique(dest)
         for s in touched:
-            self.shards[s].delete_batch(keys[dest == s])
+            with self._on(s):
+                self.shards[s].delete_batch(keys[dest == s])
         self._touch(touched)
         self._maybe_rebalance()
 
@@ -692,7 +746,8 @@ class ShardedDynamicIndex:
         else:
             # Delta-hot: flush the tier into the base, refitting only the
             # leaves that hold delta entries.
-            self.shards[hot].flush_delta()
+            with self._on(hot):
+                self.shards[hot].flush_delta()
         self.rebalances += 1
         self._touch([hot])
 
@@ -720,18 +775,21 @@ class ShardedDynamicIndex:
             if c <= 0:
                 return False
             moved, split_key = a[c:], float(a[c - 1])
-            self.shards[src].shed_suffix(split_key)
+            with self._on(src):
+                self.shards[src].shed_suffix(split_key)
             self.splits[src] = split_key
         else:
             c = int(np.searchsorted(a, a[m], side="left"))
             if c <= 0:
                 return False
             moved, split_key = a[:c], float(a[c - 1])
-            self.shards[src].shed_prefix(split_key)
+            with self._on(src):
+                self.shards[src].shed_prefix(split_key)
             self.splits[dst] = split_key
         if moved.size <= self.migrate_headroom_factor * \
                 recv.insertion_headroom:
-            recv.insert_batch(moved)        # rides the delta tier
+            with self._on(dst):
+                recv.insert_batch(moved)    # rides the delta tier
             self.migrations_incremental += 1
         else:
             live = recv.live_keys()
@@ -743,9 +801,10 @@ class ShardedDynamicIndex:
 
     def _rebuild_shard(self, s: int, keys: np.ndarray) -> None:
         from .updates import DynamicRMI
-        self.shards[s] = DynamicRMI.build(
-            jnp.asarray(keys), pool=self.pool, eps=self.eps,
-            n_leaves=self.n_leaves, **self.build_kwargs)
+        with self._on(s):
+            self.shards[s] = DynamicRMI.build(
+                np.asarray(keys), pool=self.pool, eps=self.eps,
+                n_leaves=self.n_leaves, **self.build_kwargs)
 
     # -- drift maintenance -------------------------------------------------
     def drift_scores(self) -> np.ndarray:
@@ -776,7 +835,8 @@ class ShardedDynamicIndex:
             if s not in latched and not (d.n_inserts > d.budget).any():
                 continue
             rb0 = d.rebuilds
-            n = d.maybe_swap()
+            with self._on(s):
+                n = d.maybe_swap()
             if n or d.rebuilds != rb0:
                 total += n
                 self._touch([s])
@@ -786,30 +846,33 @@ class ShardedDynamicIndex:
     # -- serving: the per-shard slice cache --------------------------------
     # Invalidation contract (module docstring): mutations mark shards dirty
     # via _touch; _stacked rewrites exactly the dirty rows of the stacked
-    # arrays (one batched row-scatter per array), re-assembling from
-    # scratch only when the *global* capacity class changed.
+    # arrays, re-assembling from scratch only when the *global* capacity
+    # class changed.
     @staticmethod
     def _pads(bcap: int, dcap: int):
         from ..kernels.lookup import pad_capacity as padk
-        padz = lambda a, c: jnp.pad(a, (0, c - a.shape[0]))
-        padp = lambda a, c: jnp.pad(a, (0, c + 1 - a.shape[0]), mode="edge")
+        padz = lambda a, c: a if a.shape[0] == c else \
+            jnp.pad(a, (0, c - a.shape[0]))
+        padp = lambda a, c: a if a.shape[0] == c + 1 else \
+            jnp.pad(a, (0, c + 1 - a.shape[0]), mode="edge")
         return padk, padz, padp
 
     def _slice_rows(self, s: int, bcap: int, dcap: int) -> dict:
-        """One shard's slice set, padded to the global capacity classes —
-        the unit of incremental restacking."""
+        """One shard's slice set, padded (on its home device) to the global
+        capacity classes — the unit of incremental restacking."""
         d = self.shards[s]
         padk, padz, padp = self._pads(bcap, dcap)
-        return dict(
-            route_n=jnp.float64(d.route_n),
-            base=padk(d.index.keys, bcap),
-            bdead=padz(d.base_dead, bcap),
-            bpsum=padp(d.base_psum, bcap),
-            dk=padk(d.delta_keys, dcap),
-            ddead=padz(d.delta_dead, dcap),
-            dpsum=padp(d.delta_psum, dcap),
-            err_lo=d.index.err_lo,
-            err_hi=d.index.err_hi)
+        with self._on(s):
+            return dict(
+                route_n=jnp.float64(d.route_n),
+                base=padk(d.index.keys, bcap),
+                bdead=padz(d.base_dead, bcap),
+                bpsum=padp(d.base_psum, bcap),
+                dk=padk(d.delta_keys, dcap),
+                ddead=padz(d.delta_dead, dcap),
+                dpsum=padp(d.delta_psum, dcap),
+                err_lo=d.index.err_lo,
+                err_hi=d.index.err_hi)
 
     _ROW_KEYS = ("route_n", "base", "bdead", "bpsum", "dk", "ddead",
                  "dpsum", "err_lo", "err_hi")
@@ -839,7 +902,9 @@ class ShardedDynamicIndex:
         fixed; for a maintained index the sweep is a no-op (``_touch``
         shrinks eagerly)."""
         for s, d in enumerate(self.shards):
-            if d.shrink_capacity():
+            with self._on(s):
+                shrank = d.shrink_capacity()
+            if shrank:
                 self.capacity_shrinks += 1
                 self._bcaps[s] = d.index.keys.shape[0]
                 self._dcaps[s] = d.delta_keys.shape[0]
@@ -858,20 +923,43 @@ class ShardedDynamicIndex:
             leaf_kind=self.shards[0].index.leaf_kind,
             iters=int(self._iters_vec.max()),   # tracelint: ok[hot-sync](np mirror)
             packed=None,
-            **{k: jnp.stack([r[k] for r in rows]) for k in self._ROW_KEYS})
+            **{k: self._assemble({s: r[k] for s, r in enumerate(rows)})
+               for k in self._ROW_KEYS})
         self.restack_full += 1
         self._dirty.clear()
         return self._stack
 
+    def _assemble(self, rows: dict, old: Array | None = None) -> Array:
+        """Stack per-shard rows (``rows[s]``) along a leading shard axis
+        straight onto the mesh (:meth:`shard_sharding`): each device gets
+        only its own shard's row, so no device stages the whole stack.
+        With ``old`` (the previous stack), devices whose shard has no new
+        row keep their buffers of ``old``."""
+        sh = self.shard_sharding()
+        shape = old.shape if old is not None else \
+            (self.n_shards,) + next(iter(rows.values())).shape
+        keep = {} if old is None else \
+            {b.device: b.data for b in old.addressable_shards}
+        bufs = []
+        for dev, index in sh.addressable_devices_indices_map(shape).items():
+            s = index[0].start or 0     # one shard per device slot
+            if s in rows:
+                with jax.default_device(dev):
+                    bufs.append(jnp.expand_dims(rows[s], 0))
+            else:
+                bufs.append(keep[dev])
+        return jax.make_array_from_single_device_arrays(shape, sh, bufs)
+
     def _restack_rows(self, st: dict, ids: list, bcap: int,
                       dcap: int) -> None:
-        """Rewrite the dirty shards' rows of the stacked arrays in place —
-        one batched row-scatter per array, O(touched) slice work."""
+        """Rewrite the dirty shards' rows of the stacked arrays — new
+        buffers on the dirty shards' devices, one batched row-scatter per
+        model array — O(touched) slice work."""
         rows = [self._slice_rows(s, bcap, dcap) for s in ids]
         idx = jnp.asarray(ids)
         for k in self._ROW_KEYS:
-            st[k] = scatter_rows_donated(
-                st[k], idx, jnp.stack([r[k] for r in rows]))
+            st[k] = self._assemble(
+                {s: r[k] for s, r in zip(ids, rows, strict=True)}, st[k])
         scat = lambda t, *r: scatter_rows_donated(t, idx, jnp.stack(r))
         st["root"] = jax.tree.map(
             scat, st["root"], *[self.shards[s].index.root for s in ids])
@@ -896,8 +984,9 @@ class ShardedDynamicIndex:
         scale folded in, so the kernel traces once with static
         ``route_n = n_leaves``)."""
         d = self.shards[s]
-        _, mat, vec = d.index.packed_tables()
-        return d.packed_root(self.n_leaves), mat, vec
+        with self._on(s):
+            _, mat, vec = d.index.packed_tables()
+            return d.packed_root(self.n_leaves), mat, vec
 
     def _packed_stack(self, st: dict) -> tuple:
         """Stacked per-shard kernel tables (lazy: first kernel-path find,
@@ -1056,10 +1145,11 @@ def _sharded_dynamic_find_fn(mesh: Mesh, axis: str, *, n_leaves: int,
         return found.astype(bool), rank
 
     fn = jax.shard_map(
-        shard_fn, mesh=mesh,
+        shard_fn, mesh=_auto_axes(mesh),
         in_specs=(P(), P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
                   P(axis), P(axis), P(axis), P(axis)),
-        out_specs=(P(axis), P(axis)), check_vma=True)
+        out_specs=(P(axis), P(axis)),
+        check_vma=_checks_vma(use_kernel, interpret))
     return jax.jit(fn)
 
 
@@ -1123,10 +1213,11 @@ def _sharded_dynamic_range_fn(mesh: Mesh, axis: str, *, n_leaves: int,
         return rlo, rhi
 
     fn = jax.shard_map(
-        shard_fn, mesh=mesh,
+        shard_fn, mesh=_auto_axes(mesh),
         in_specs=(P(), P(axis), P(axis), P(axis), P(axis), P(axis), P(axis),
                   P(axis), P(axis), P(axis), P(axis)),
-        out_specs=(P(axis), P(axis)), check_vma=True)
+        out_specs=(P(axis), P(axis)),
+        check_vma=_checks_vma(use_kernel, interpret))
     return jax.jit(fn)
 
 
@@ -1218,12 +1309,13 @@ def _tenant_stacked_find_fn(mesh: Mesh, axis: str, *, n_tenants: int,
         return jnp.stack(founds), jnp.stack(ranks)
 
     fn = jax.shard_map(
-        shard_fn, mesh=mesh,
+        shard_fn, mesh=_auto_axes(mesh),
         in_specs=(P(), P(None, axis), P(None, axis), P(None, axis),
                   P(None, axis), P(None, axis), P(None, axis),
                   P(None, axis), P(None, axis), P(None, axis),
                   P(None, axis)),
-        out_specs=(P(None, axis), P(None, axis)), check_vma=True)
+        out_specs=(P(None, axis), P(None, axis)),
+        check_vma=_checks_vma(use_kernel, interpret))
     return jax.jit(fn)
 
 
@@ -1289,10 +1381,11 @@ def _tenant_stacked_range_fn(mesh: Mesh, axis: str, *, n_tenants: int,
         return jnp.stack(rlos), jnp.stack(rhis)
 
     fn = jax.shard_map(
-        shard_fn, mesh=mesh,
+        shard_fn, mesh=_auto_axes(mesh),
         in_specs=(P(), P(None, axis), P(None, axis), P(None, axis),
                   P(None, axis), P(None, axis), P(None, axis),
                   P(None, axis), P(None, axis), P(None, axis),
                   P(None, axis)),
-        out_specs=(P(None, axis), P(None, axis)), check_vma=True)
+        out_specs=(P(None, axis), P(None, axis)),
+        check_vma=_checks_vma(use_kernel, interpret))
     return jax.jit(fn)

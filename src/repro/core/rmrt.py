@@ -238,7 +238,7 @@ def lookup(index: RMRTIndex, queries: Array, *, path: str = "auto",
            use_kernel: bool | None = None,
            clamp_iters: bool = True) -> Array:
     """Serving lookup.  ``path="kernel"`` is the fused Pallas kernel —
-    descent AND clamped search in one kernel; the masked-descent jnp path
+    descent, then the clamped search kernel; the masked-descent jnp path
     below is the CPU fast path, the kernel's f64 reference, and the f64
     fallback.  Same path-selection semantics as ``rmi.lookup``
     (``core.paths.resolve_path``; ``use_kernel`` is the deprecated shim)."""

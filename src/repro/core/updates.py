@@ -19,7 +19,7 @@ the lookup path:
                  probe + tombstone mask.  ``rank`` counts *live* keys < q
                  across BOTH tiers (the seed composed base_pos with only the
                  routed leaf's buffer, dropping buffered inserts in earlier
-                 leaves).  On TPU (or ``use_kernel=True``) the whole pass is
+                 leaves).  On TPU (or ``path="kernel"``) the base search is
                  one Pallas kernel call (``kernels.ops.dynamic_index_lookup``);
                  the jnp path here is its f64 oracle and the CPU fast path.
   rebuild        Lemma 4.1 budget exhaustion triggers a *batched* leaf
@@ -62,7 +62,7 @@ shape — and therefore the jit cache — only changes on capacity doubling.
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field, fields, is_dataclass, replace
 
 import jax
 import jax.numpy as jnp
@@ -103,7 +103,9 @@ def _compact_sorted(keys: Array, keep: Array, payloads: tuple,
     count before it (one cumsum + scatter — order, hence sortedness, is
     preserved with no sort)."""
     cap = keys.shape[0]
-    tgt = jnp.arange(cap) - jnp.cumsum(~keep) + (~keep)   # exclusive cumsum
+    drop = (~keep).astype(jnp.int32)
+    tgt = jnp.arange(cap, dtype=jnp.int32) - jnp.cumsum(drop, dtype=jnp.int32) \
+        + drop                                              # exclusive cumsum
     tgt = jnp.where(keep, tgt, cap)
     ck = jnp.full((cap,), jnp.inf, keys.dtype).at[tgt].set(keys, mode="drop")
     cp = tuple(jnp.full((cap,), f, p.dtype).at[tgt].set(p, mode="drop")
@@ -138,7 +140,7 @@ def _merge_sorted(ak: Array, bk: Array, cap_out: int, a_payloads: tuple,
     posb = jnp.arange(nb) + jnp.searchsorted(ak, bk, side="right")  # sorted
     i = jnp.arange(cap_out)
     ind = jnp.bincount(posb, length=cap_out)          # oob posb drop (trunc)
-    cum = jnp.cumsum(ind)
+    cum = jnp.cumsum(ind, dtype=jnp.int32)
     from_b = ind > 0
     bl = cum - ind                                    # exclusive
     ai = jnp.clip(i - bl, 0, na - 1)
@@ -218,7 +220,7 @@ def _psum(dead: Array) -> Array:
     """Exclusive prefix sum of a tombstone bitmap, length n + 1 (so a gather
     at position n yields the total dead count)."""
     return jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                            jnp.cumsum(dead.astype(jnp.int32))])
+                            jnp.cumsum(dead, dtype=jnp.int32)])
 
 
 @jax.jit
@@ -238,7 +240,7 @@ def _delete_jit(base_keys: Array, base_dead: Array, dk: Array, ddead: Array,
     def mark(keys, dead, skip):
         n = keys.shape[0]
         psum = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                jnp.cumsum(dead.astype(jnp.int32))])
+                                jnp.cumsum(dead, dtype=jnp.int32)])
         lo = jnp.searchsorted(keys, q, side="left")
         hi = jnp.searchsorted(keys, q, side="right")
         tgt = lo + (psum[hi] - psum[lo])
@@ -451,6 +453,57 @@ def _merge_base_jit(base_keys: Array, base_dead: Array, moved: Array,
 # ---------------------------------------------------------------------------
 # The dynamic index.
 # ---------------------------------------------------------------------------
+def _host_device():
+    """The CPU device that builds and leaf refits run on, or None when the
+    default backend is the CPU itself.  On an accelerator these programs
+    are f64 prefix scans (per-leaf moments, segmented residual min/max),
+    which XLA:TPU does not compile in useful time; serving and updates only
+    need gathers, searches and int32 prefix sums, which it does."""
+    if jax.default_backend() == "cpu":
+        return None
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "index builds and leaf refits run on the host CPU backend, "
+            "which JAX does not see: add cpu to JAX_PLATFORMS (for "
+            "example JAX_PLATFORMS=tpu,cpu)") from e
+
+
+def _to_default_device(obj):
+    """``obj`` with every jax array inside it — through pytrees and
+    dataclass fields (but not a shared ``pool``) — copied to the default
+    device, uncommitted like the arrays jnp creates there."""
+    def move(v):
+        if isinstance(v, jax.Array):
+            return jnp.asarray(np.asarray(v))
+        if is_dataclass(v) and not isinstance(v, type):
+            return replace(v, **{f.name: jax.tree.map(move, getattr(v, f.name))
+                                 for f in fields(v) if f.name != "pool"})
+        return v
+    return jax.tree.map(move, obj)
+
+
+def _on_host(fn, *args, **kwargs):
+    """``fn(*args, **kwargs)``, on the host CPU backend when there is one
+    to use (:func:`_host_device`): array arguments are copied there and
+    the result moves back to the default device."""
+    host = _host_device()
+    if host is None:
+        return fn(*args, **kwargs)
+    args = [np.asarray(a) if isinstance(a, jax.Array) else a for a in args]
+    with jax.default_device(host):
+        out = fn(*args, **kwargs)
+    return _to_default_device(out)
+
+
+def _refit(idx: rmi_mod.RMIIndex, keys: Array, route_n: int,
+           **fit_kwargs) -> rmi_mod.LeafFit:
+    """``rmi.fit_leaves`` over ``keys`` routed by ``idx``'s frozen root."""
+    b = _routed_buckets(idx.root_kind, idx.root, keys, idx.n_leaves, route_n)
+    return rmi_mod.fit_leaves(keys, b, idx.n_leaves, **fit_kwargs)
+
+
 @dataclass
 class DynamicRMI:
     """RMI base tier + sorted device delta tier + Lemma 4.1 rebuild policy.
@@ -517,12 +570,21 @@ class DynamicRMI:
                                         # change after build)
 
     @classmethod
-    def build(cls, keys, pool=None, eps: float = 0.9,
-              reuse_on_rebuild: bool | None = None,
-              compact_dead_ratio: float | None = _COMPACT_RATIO,
-              drift_bins: int = 0, drift_hi: float = 0.15,
-              drift_lo: float = 0.05, swap_on_drift: bool = False,
-              **rmi_kwargs):
+    def build(cls, keys, **kwargs) -> "DynamicRMI":
+        """Build over sorted ``keys`` (options: :meth:`_build`).
+
+        On an accelerator the build runs on the host's CPU backend
+        (:func:`_host_device`) and the finished state moves to the default
+        device."""
+        return _on_host(cls._build, keys, **kwargs)
+
+    @classmethod
+    def _build(cls, keys, pool=None, eps: float = 0.9,
+               reuse_on_rebuild: bool | None = None,
+               compact_dead_ratio: float | None = _COMPACT_RATIO,
+               drift_bins: int = 0, drift_hi: float = 0.15,
+               drift_lo: float = 0.05, swap_on_drift: bool = False,
+               **rmi_kwargs):
         """``drift_bins > 0`` turns on the online drift monitor
         (``core.drift``) at that histogram resolution, with the
         [drift_lo, drift_hi] hysteresis band; ``swap_on_drift`` addition-
@@ -912,14 +974,12 @@ class DynamicRMI:
         # route to the dump bucket and drop out of every segment op).  The
         # fit only sees the finite prefix — sliced at a quantized boundary
         # so the O(n) fit passes skip the capacity padding without
-        # multiplying jit cache entries.
-        buckets = _routed_buckets(idx.root_kind, idx.root, new_base, L,
-                                  self.route_n)
+        # multiplying jit cache entries — and runs where builds run.
         sl = min(cap_new, -(-self.base_n // 8192) * 8192)
         want_reuse = self.reuse_on_rebuild if self.reuse_on_rebuild \
             is not None else idx.leaf_kind != "linear"
-        fit = rmi_mod.fit_leaves(
-            new_base[:sl], buckets[:sl], L, kind=idx.leaf_kind,
+        fit = _on_host(
+            _refit, idx, new_base[:sl], self.route_n, kind=idx.leaf_kind,
             pool=self.pool if want_reuse else None, paper_bounds=False,
             train_steps=self.build_kwargs.get("train_steps", 300),
             refit_mask=rmask, sorted_buckets=idx.root_kind == "linear")

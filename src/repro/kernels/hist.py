@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .call import pallas_call
+
 TILE_R, TILE_C = 8, 128
 TILE = TILE_R * TILE_C
 
@@ -60,13 +62,12 @@ def hist_pallas(keys: jax.Array, m: int, lo, hi, *,
     def kern(prm_ref, keys_ref, out_ref):
         _hist_kernel(prm_ref, keys_ref, out_ref, m=m, n_valid=n)
 
-    counts = pl.pallas_call(
-        kern,
+    counts = pallas_call(
+        kern, prm, kp,
         grid=(n_pad // TILE,),
         in_specs=[pl.BlockSpec((1, 128), lambda i: (0, 0)),
                   pl.BlockSpec((1, TILE_R, TILE_C), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((1, m_pad), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, m_pad), jnp.float32),
-        interpret=interpret,
-    )(prm, kp)
+        interpret=interpret)
     return counts[0, :m] / jnp.float32(n)

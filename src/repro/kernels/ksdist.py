@@ -11,7 +11,8 @@ where A = H + P(prefix) tables are precomputed per side (core.reuse.
 pool_prefix_tables). TPU adaptation of the paper's sequential priority-queue
 scan: one grid cell processes a (TL, TP) tile of the matrix with both
 operand tiles resident in VMEM; the m-dim broadcast stays on-chip
-(TL*TP*m f32 = 1 MiB at 64x64x64), so HBM traffic is O(L*m + P*m + L*P)
+(TL*TP*m f32 = 2 MiB at 64x128x64; TP is one lane tile, the
+narrowest output block Mosaic accepts), so HBM traffic is O(L*m + P*m + L*P)
 instead of the O(L*P*m) a naive XLA broadcast materializes.
 """
 from __future__ import annotations
@@ -20,7 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-TL, TP = 64, 64
+from .call import pallas_call
+
+TL, TP = 64, 128
 
 
 def _ksdist_kernel(tgt_a_ref, tgt_pt_ref, pool_a_ref, pool_ps_ref, out_ref):
@@ -65,8 +68,8 @@ def ksdist_pallas(tgt_hists: jax.Array, pool_a: jax.Array, pool_ps: jax.Array,
     pa_p = pad2(pool_a.astype(jnp.float32), P_pad, -10.0, +2.0)
     pp_p = pad2(pool_ps.astype(jnp.float32), P_pad, +10.0, 0.0)
 
-    out = pl.pallas_call(
-        _ksdist_kernel,
+    out = pallas_call(
+        _ksdist_kernel, ta_p, tp_p, pa_p, pp_p,
         grid=(L_pad // TL, P_pad // TP),
         in_specs=[
             pl.BlockSpec((TL, m_pad), lambda i, j: (i, 0)),
@@ -76,6 +79,5 @@ def ksdist_pallas(tgt_hists: jax.Array, pool_a: jax.Array, pool_ps: jax.Array,
         ],
         out_specs=pl.BlockSpec((TL, TP), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((L_pad, P_pad), jnp.float32),
-        interpret=interpret,
-    )(ta_p, tp_p, pa_p, pp_p)
+        interpret=interpret)
     return out[:L, :P]

@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .call import pallas_call
+
 TILE = 1024     # keys per grid step
 TB = 512        # buckets per grid step
 
@@ -66,8 +68,8 @@ def linfit_sums_pallas(x: jax.Array, y: jax.Array, buckets: jax.Array,
     def kern(x_ref, y_ref, b_ref, out_ref):
         _linfit_kernel(x_ref, y_ref, b_ref, out_ref, n_valid=n)
 
-    out = pl.pallas_call(
-        kern,
+    out = pallas_call(
+        kern, xp, yp, bp,
         grid=(b_pad // TB, n_pad // TILE),
         in_specs=[
             pl.BlockSpec((1, 8, TILE // 8), lambda j, i: (i, 0, 0)),
@@ -76,6 +78,5 @@ def linfit_sums_pallas(x: jax.Array, y: jax.Array, buckets: jax.Array,
         ],
         out_specs=pl.BlockSpec((8, TB), lambda j, i: (0, j)),
         out_shape=jax.ShapeDtypeStruct((8, b_pad), jnp.float32),
-        interpret=interpret,
-    )(xp, yp, bp)
+        interpret=interpret)
     return out[:5, :n_buckets].T                            # (n_buckets, 5)

@@ -1,32 +1,42 @@
-"""Pallas TPU kernel: fused learned-index lookup — the serving hot path.
+"""Pallas TPU kernel: window-clamped learned-index lookup — the serving
+hot path.
 
-One kernel fuses all four stages the paper executes per query
-(root routing -> leaf predict -> error-bound window -> bounded search):
+Each query runs the paper's four stages (root routing -> leaf predict ->
+error-bound window -> bounded search):
 
-  1. in-kernel root routing: the root model (linear or tiny MLP) runs on the
-     query tile and buckets each query into its leaf,
-  2. gather-free leaf-param fetch: the compact per-leaf tables — (H, L) model
-     params and (L,) bounds, a few hundred KB even at 16k leaves — stay
-     resident in VMEM and are indexed per query *inside* the kernel. The old
-     path materialized (Q, H)x3 pre-gathered parameter arrays in XLA before
-     the kernel; at serving batch sizes Q >> L that gather traffic dominated,
-  3. window clamp from the leaf's error bounds,
-  4. branchless binary search with a *static iteration count derived from the
-     index's error window* (paper §4: the reuse bound caps the search range),
-     not from log2(n_keys) — 3-6x fewer iterations for tight-epsilon indexes.
+  1-3. routing and window: the root model (linear or tiny MLP) buckets each
+       query into its leaf, the leaf's model predicts a position, and the
+       leaf's error bounds clamp it to a window.  The compact per-leaf
+       tables — (3H, Lp) model params and (8, Lp) scalars, lane-major — are
+       gathered per query by XLA in the jitted wrapper (``_route_window``;
+       RMRT: ``_rmrt_route_window``).
+  4.   the bounded search, in the Pallas kernel: a branchless binary search
+       with a *static iteration count derived from the index's error
+       window* (paper §4: the reuse bound caps the search range), not from
+       log2(n_keys).
 
 Memory layout: queries are tiled TQ at a time (grid dim 0) and the key shard
-is BlockSpec-tiled TILE keys at a time (grid dim 1, innermost), so the VMEM
-working set is TQ + TILE + tables regardless of shard size — shards beyond
-the old ~3M-key single-block cap are servable. Each (i, j) grid step searches
-query tile i's windows restricted to key tile j and min-merges the candidate
-into the revisited output block; left-boundary results compose across tiles
-because positions increase with j. Queries whose window misses tile j
-contribute nothing.
+is BlockSpec-tiled TILE keys at a time as (TILE/128, 128) rows (grid dim 1,
+innermost), so the VMEM working set is TQ + TILE regardless of shard size.
+Each (i, j) grid step searches query tile i's windows restricted to key
+tile j and min-merges the candidate into the revisited output block;
+left-boundary results compose across tiles because positions increase with
+j.  Queries whose window misses tile j contribute nothing, and a step whose
+tile no window touches skips its work.
 
-Leaf tables are packed lane-major — (3H, Lp) params, (8, Lp) scalars, leaves
-on the 128-lane axis — so per-query fetch is a VMEM dynamic gather along
-lanes, the same primitive as the key probe.
+The search without a gather.  Mosaic gathers only inside one vreg (8
+sublanes or 128 lanes), so the kernel cannot probe ``keys[mid]`` per query.
+It needs only the count ``c`` of tile keys below the query instead: the tile
+is sorted, so ``keys[mid] < q`` holds exactly when ``mid < c``, and the
+bisection replayed on integers with that predicate visits the same midpoints
+and stops at the same position as the probing search — including queries
+whose window is wider than the static depth (their non-converged position is
+the one ``kernels.ref`` computes, and the ops wrapper's seam verification
+owns them).  ``c`` comes in two steps (``_count_below``): the row whose head
+is the last one below q, by comparing q with the 1-per-row heads; then that
+row, fetched for every query at once by a one-hot matmul on the MXU over the
+byte planes of the key bits (bytes are exact in bf16, and each output sums a
+single nonzero product), so the fetched keys are bit-exact, +inf included.
 
 RMRT node-table packing (``pack_rmrt``): the flat level-synchronous node
 arrays of ``core.rmrt.RMRTIndex`` pack into the same lane-major layout,
@@ -41,9 +51,8 @@ nodes on the 128-lane axis padded to Np = 128-multiple:
                      row 3 y_start         row 6 is_leaf (0.0 / 1.0)
 
 so the fixed-depth masked descent (``_rmrt_route_window``) is a per-level
-VMEM lane gather + predict + re-bucket, entirely in-kernel — no XLA
-pre-routing pass.  Internal nodes carry err rows of 0 and leaves carry
-child_base -1; neither is ever consumed on the other branch of the
+gather + predict + re-bucket.  Internal nodes carry err rows of 0 and leaves
+carry child_base -1; neither is ever consumed on the other branch of the
 ``is_leaf`` select.  Padded lanes are unreachable (descent starts at node 0
 and child ids stay < num_nodes).
 
@@ -59,12 +68,14 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-TQ = 1024          # queries per grid step
-TILE_MAX = 1 << 18  # keys per VMEM tile (1 MiB f32)
-H = 4              # paper's hidden width
-ROOT_ROWS = 8      # packed root block: rows [w1, b1, w2, b2|meta] x 128 lanes
+from .call import pallas_call
+
+LANES = 128
+TQ = 1024           # queries per grid step
+TILE_MAX = 1 << 16  # keys per VMEM tile (256 KiB f32, 512 rows of 128)
+H = 4               # paper's hidden width
+ROOT_ROWS = 8       # packed root block: rows [w1, b1, w2, b2|meta] x 128 lanes
 
 
 def search_iters(err_lo, err_hi, n_keys: int) -> int:
@@ -156,12 +167,12 @@ def pad_packed_leaves(mat, vec, n_live: int, lp_to: int):
 
 def _route_window(root, mat, vec, q, *, n_keys: int, n_leaves: int, lp: int,
                   route_n: int, root_kind: str, leaf_kind: str):
-    """Stages 1-3 on a query tile (pure jnp on values — shared by the static
-    and dynamic kernel bodies): in-kernel root routing (scaled by
-    ``route_n``, the build-time key count the routing is frozen at),
-    gather-free leaf fetch from the VMEM-resident tables, error-bound
-    window clamped to the *current* key count ``n_keys``."""
-    # ---- stage 1: in-kernel root routing --------------------------------
+    """Stages 1-3 on f32 queries (XLA, shared by the static and dynamic
+    lookups): root routing (scaled by ``route_n``, the build-time key count
+    the routing is frozen at), per-query leaf fetch from the flattened
+    lane-major tables, error-bound window clamped to the *current* key
+    count ``n_keys``."""
+    # ---- stage 1: root routing -------------------------------------------
     if root_kind == "linear":
         rpred = root[0, 0] * q + root[3, 0]
     else:
@@ -170,8 +181,8 @@ def _route_window(root, mat, vec, q, *, n_keys: int, n_leaves: int, lp: int,
     b = jnp.clip((rpred * (n_leaves / route_n)).astype(jnp.int32),
                  0, n_leaves - 1)
 
-    # ---- stage 2: gather-free leaf fetch (VMEM-resident tables) ---------
-    row = lambda flat, r: jnp.take(flat, b + r * lp)       # (TQ,) per row
+    # ---- stage 2: leaf fetch ---------------------------------------------
+    row = lambda flat, r: jnp.take(flat, b + r * lp)       # (Q,) per row
     if leaf_kind == "linear":
         pred = row(mat, 0) * q + row(vec, 0)
     else:
@@ -180,7 +191,7 @@ def _route_window(root, mat, vec, q, *, n_keys: int, n_leaves: int, lp: int,
             hk = jnp.maximum(q * row(mat, k) + row(mat, H + k), 0.0)
             pred = pred + hk * row(mat, 2 * H + k)
 
-    # ---- stage 3: error-bound window ------------------------------------
+    # ---- stage 3: error-bound window ---------------------------------------
     lo = jnp.clip(jnp.floor(pred + row(vec, 1)), 0, n_keys - 1
                   ).astype(jnp.int32)
     hi = jnp.clip(jnp.ceil(pred + row(vec, 2)) + 1.0, 1, n_keys
@@ -188,66 +199,87 @@ def _route_window(root, mat, vec, q, *, n_keys: int, n_leaves: int, lp: int,
     return lo, hi
 
 
-def _tile_search_merge(keys_ref, q, lo_ref, hi_ref, out_ref, j, *,
-                       n_keys: int, tile: int, tile_iters: int,
-                       right: bool = False):
-    """Stage 4, shared by every lookup kernel: window-clamped branchless
-    search of query tile ``q`` restricted to key tile ``j``, min-merged into
-    the revisited output block (left boundaries compose across tiles because
-    positions increase with j).
-
-    ``right=True`` searches the *right* boundary (first position with
-    key > q — the rightmost-rank side of a range endpoint).  The min-merge
-    composes identically: the first tile containing a key > q yields the
-    winning candidate, tiles whose clipped window is entirely <= q converge
-    to l == thi (invalid candidate), and +inf capacity padding compares > q
-    for every finite query, so pads never shift a right boundary either."""
-    lo = lo_ref[...].reshape(TQ)
-    hi = hi_ref[...].reshape(TQ)
-    base = j * tile
-    tlo = jnp.clip(lo - base, 0, tile)
-    thi = jnp.clip(hi - base, 0, tile)
-    keys = keys_ref[...].reshape(tile)
-
-    def body(_, lh):
-        l, h2 = lh
-        active = h2 - l > 0
-        mid = (l + h2) // 2
-        kv = jnp.take(keys, jnp.clip(mid, 0, tile - 1))
-        below = kv <= q if right else kv < q
-        nl = jnp.where(below, mid + 1, l)
-        nh = jnp.where(below, h2, mid)
-        return (jnp.where(active, nl, l), jnp.where(active, nh, h2))
-
-    l, _ = jax.lax.fori_loop(0, tile_iters, body, (tlo, thi))
-    cand = jnp.where(l < thi, base + l, n_keys)
-
-    cur = out_ref[...].reshape(TQ)
-    out_ref[...] = jnp.minimum(cur, cand).reshape(out_ref.shape)
+# ---------------------------------------------------------------------------
+# Stage 4: the window-clamped search kernel, shared by every lookup.
+# ---------------------------------------------------------------------------
+def _byte_planes(keys):
+    """(R, 4 * 128) bf16 of a (R, 128) f32 tile: the four bytes of each
+    key's bit pattern, lane-concatenated low byte first.  Each is an integer
+    in [0, 255], exact in bf16."""
+    bits = jax.lax.bitcast_convert_type(keys, jnp.int32)
+    return jnp.concatenate(
+        [jnp.bitwise_and(jax.lax.shift_right_logical(bits, 8 * k), 0xFF)
+         .astype(jnp.float32).astype(jnp.bfloat16) for k in range(4)],
+        axis=1)
 
 
-def _lookup_kernel(root_ref, mat_ref, vec_ref, q_ref, keys_ref, out_ref,
-                   lo_ref, hi_ref, *,
-                   n_keys: int, n_leaves: int, lp: int, tile: int,
-                   tile_iters: int, root_kind: str, leaf_kind: str):
+def _count_below(keys, planes, q, *, right: bool):
+    """(1, N) int32: for each query of the row ``q`` (1, N), how many keys
+    of the sorted tile ``keys`` (R, 128) compare below it (``<``, or ``<=``
+    when ``right``).  ``planes`` is ``_byte_planes(keys)``."""
+    below = (lambda k: k <= q) if right else (lambda k: k < q)
+    cmp = below(keys[:, 0:1])                                   # (R, N)
+    rc = jnp.sum(cmp.astype(jnp.int32), axis=0, keepdims=True)  # rows below
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, cmp.shape, 0) == rc - 1
+              ).astype(jnp.bfloat16)
+    got = jax.lax.dot_general(planes, onehot, (((0,), (0,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+    b = got.astype(jnp.int32)                                   # (512, N)
+    bits = (b[:LANES] | (b[LANES:2 * LANES] << 8)
+            | (b[2 * LANES:3 * LANES] << 16) | (b[3 * LANES:] << 24))
+    row = jax.lax.bitcast_convert_type(bits, jnp.float32)  # [l, i]: row rc-1
+    cnt = jnp.sum(below(row).astype(jnp.int32), axis=0, keepdims=True)
+    return jnp.where(rc > 0, LANES * (rc - 1) + cnt, 0)
+
+
+def _bisect(l, h, c, iters: int):
+    """The branchless window search with ``keys[mid] < q`` replaced by the
+    equivalent ``mid < c`` (module docstring): ``iters`` steps over
+    [l, h), returning the final left end."""
+    for _ in range(iters):
+        active = h - l > 0
+        mid = (l + h) >> 1
+        below = mid < c
+        l = jnp.where(active & below, mid + 1, l)
+        h = jnp.where(active & ~below, mid, h)
+    return l
+
+
+def _search_kernel(*refs, sides: tuple, n_keys: int, tile: int,
+                   tile_iters: int):
+    """One (query tile i, key tile j) step for each endpoint set e (the
+    range lookup searches two: left bounds of lo, right bounds of hi).
+
+    refs: q x E, lo x E, hi x E, keys, out x E."""
+    E = len(sides)
+    q_refs, lo_refs, hi_refs = refs[:E], refs[E:2 * E], refs[2 * E:3 * E]
+    keys_ref = refs[3 * E]
+    out_refs = refs[3 * E + 1:]
+    load = lambda ref: ref[...].reshape(1, TQ)
     j = pl.program_id(1)
-    q = q_ref[...].reshape(TQ)
+    base = j * tile
 
-    # Stages 1-3 depend only on the query tile: run them once per query tile
-    # (j == 0) and stash the window in VMEM scratch for the key-tile sweep.
     @pl.when(j == 0)
     def _():
-        lo, hi = _route_window(
-            root_ref[...].reshape(ROOT_ROWS, 128),
-            mat_ref[...].reshape(3 * H * lp), vec_ref[...].reshape(8 * lp),
-            q, n_keys=n_keys, n_leaves=n_leaves, lp=lp, route_n=n_keys,
-            root_kind=root_kind, leaf_kind=leaf_kind)
-        lo_ref[...] = lo.reshape(lo_ref.shape)
-        hi_ref[...] = hi.reshape(hi_ref.shape)
-        out_ref[...] = hi.reshape(out_ref.shape)
+        for e in range(E):
+            out_refs[e][...] = hi_refs[e][...]
 
-    _tile_search_merge(keys_ref, q, lo_ref, hi_ref, out_ref, j,
-                       n_keys=n_keys, tile=tile, tile_iters=tile_iters)
+    wins = [(jnp.clip(load(lo_refs[e]) - base, 0, tile),
+             jnp.clip(load(hi_refs[e]) - base, 0, tile)) for e in range(E)]
+    width = functools.reduce(jnp.maximum,
+                             [jnp.max(thi - tlo) for tlo, thi in wins])
+
+    @pl.when(width > 0)
+    def _():
+        keys = keys_ref[...].reshape(tile // LANES, LANES)
+        planes = _byte_planes(keys)
+        for e, right in enumerate(sides):
+            tlo, thi = wins[e]
+            c = _count_below(keys, planes, load(q_refs[e]), right=right)
+            l = _bisect(tlo, thi, c, tile_iters)
+            cand = jnp.where(l < thi, base + l, n_keys)
+            out = jnp.minimum(load(out_refs[e]), cand)
+            out_refs[e][...] = out.reshape(out_refs[e].shape)
 
 
 def _pow2ceil(v: int) -> int:
@@ -267,8 +299,54 @@ def capacity_class(n: int, floor: int = 128) -> int:
 def pad_capacity(keys: jax.Array, cap: int) -> jax.Array:
     """+inf-pad a sorted tier (or tier slice) to its capacity class — pads
     sort past every live key, route to the dump bucket, and never win a
-    left-boundary search."""
+    left-boundary search.  A tier already at ``cap`` comes back as is (no
+    copy: at 2^27 keys a copy is 1 GiB of device memory)."""
+    if keys.shape[0] == cap:
+        return keys
     return jnp.pad(keys, (0, cap - keys.shape[0]), constant_values=jnp.inf)
+
+
+def _tiling(S: int, iters: int | None, tile: int | None) -> tuple:
+    """(tile, per-tile search depth) for ``S`` keys: ``tile`` defaults to
+    the pow2 ceiling of S capped at TILE_MAX; ``iters`` to the full depth."""
+    if tile is None:
+        tile = min(TILE_MAX, _pow2ceil(max(S, 128)))
+    assert tile % 128 == 0, "key tile must be a multiple of 128 lanes"
+    if iters is None:
+        iters = full_iters(S)
+    return tile, min(iters, full_iters(tile))
+
+
+def _window_search(qs, los, his, keys, *, sides: tuple, tile: int | None,
+                   iters: int | None, interpret: bool):
+    """Stage 4 for E endpoint sets in one pass over the key tiles: for each
+    e, the window-clamped boundary of ``qs[e]`` in the sorted ``keys``
+    within ``[los[e], his[e])`` (left boundary, or right when
+    ``sides[e]``).  Every key tile is streamed through VMEM once and
+    searched for every set.  Returns one (Q,) int32 array per set."""
+    Q = qs[0].shape[0]
+    S = keys.shape[0]
+    tile, tile_iters = _tiling(S, iters, tile)
+    q_pad = -(-Q // TQ) * TQ
+    nk = -(-S // tile)
+    blk = lambda a: jnp.pad(a, (0, q_pad - Q)).reshape(-1, 1, TQ)
+    kp = jnp.pad(keys.astype(jnp.float32), (0, nk * tile - S),
+                 constant_values=jnp.inf).reshape(nk, tile // LANES, LANES)
+    E = len(sides)
+    qspec = pl.BlockSpec((1, 1, TQ), lambda i, j: (i, 0, 0))
+    outs = pallas_call(
+        functools.partial(_search_kernel, sides=tuple(sides), n_keys=S,
+                          tile=tile, tile_iters=tile_iters),
+        *[blk(q.astype(jnp.float32)) for q in qs],
+        *[blk(lo.astype(jnp.int32)) for lo in los],
+        *[blk(hi.astype(jnp.int32)) for hi in his], kp,
+        grid=(q_pad // TQ, nk),
+        in_specs=[qspec] * (3 * E) + [
+            pl.BlockSpec((1, tile // LANES, LANES), lambda i, j: (j, 0, 0))],
+        out_specs=[qspec] * E,
+        out_shape=[jax.ShapeDtypeStruct((q_pad // TQ, 1, TQ), jnp.int32)] * E,
+        interpret=interpret)
+    return [o.reshape(-1)[:Q] for o in outs]
 
 
 def lookup_pallas(queries, root, mat, vec, keys, *, n_leaves: int,
@@ -281,105 +359,40 @@ def lookup_pallas(queries, root, mat, vec, keys, *, n_leaves: int,
     keys: (S,) sorted. ``iters`` is the static window search depth
     (see search_iters); ``tile`` the key-tile size (multiple of 128).
     """
-    Q = queries.shape[0]
     S = keys.shape[0]
-    lp = mat.shape[1]
-    q_pad = -(-Q // TQ) * TQ
-    if tile is None:
-        tile = min(TILE_MAX, _pow2ceil(max(S, 128)))
-    assert tile % 128 == 0, "key tile must be a multiple of 128 lanes"
-    s_pad = -(-S // tile) * tile
-    nk = s_pad // tile
-    if iters is None:
-        iters = full_iters(S)
-    tile_iters = min(iters, full_iters(tile))
-
-    pad1 = lambda a: jnp.pad(a.astype(jnp.float32), (0, q_pad - Q)) \
-        .reshape(-1, 8, TQ // 8)
-    kp = jnp.pad(keys.astype(jnp.float32), (0, s_pad - S),
-                 constant_values=jnp.inf).reshape(nk, 8, tile // 8)
-
-    kern = functools.partial(
-        _lookup_kernel, n_keys=S, n_leaves=n_leaves, lp=lp, tile=tile,
-        tile_iters=tile_iters, root_kind=root_kind, leaf_kind=leaf_kind)
-    out = pl.pallas_call(
-        kern,
-        grid=(q_pad // TQ, nk),
-        in_specs=[
-            pl.BlockSpec((ROOT_ROWS, 128), lambda i, j: (0, 0)),      # root
-            pl.BlockSpec((3 * H, lp), lambda i, j: (0, 0)),           # mat
-            pl.BlockSpec((8, lp), lambda i, j: (0, 0)),               # vec
-            pl.BlockSpec((1, 8, TQ // 8), lambda i, j: (i, 0, 0)),    # q
-            pl.BlockSpec((1, 8, tile // 8), lambda i, j: (j, 0, 0)),  # keys
-        ],
-        out_specs=pl.BlockSpec((1, 8, TQ // 8), lambda i, j: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((q_pad // TQ, 8, TQ // 8), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((8, TQ // 8), jnp.int32),   # lo window
-                        pltpu.VMEM((8, TQ // 8), jnp.int32)],  # hi window
-        interpret=interpret,
-    )(root, mat, vec, pad1(queries), kp)
-    return out.reshape(-1)[:Q]
+    q = queries.astype(jnp.float32)
+    lo, hi = _route_window(
+        root, mat.reshape(-1), vec.reshape(-1), q, n_keys=S,
+        n_leaves=n_leaves, lp=mat.shape[1], route_n=S, root_kind=root_kind,
+        leaf_kind=leaf_kind)
+    (out,) = _window_search((q,), (lo,), (hi,), keys, sides=(False,),
+                           tile=tile, iters=iters, interpret=interpret)
+    return out
 
 
 # ---------------------------------------------------------------------------
 # Two-tier (base + delta) dynamic lookup: the update subsystem's serving
-# kernel.  One kernel fuses the static kernel's four stages over the base
-# tier with a full-depth probe of the sorted delta tier (the device-resident
-# insert buffer, VMEM-sized by the Lemma 4.1 rebuild policy), so a find
-# under churn is a single kernel call.  The tombstone mask and two-tier rank
-# arithmetic are O(Q) gathers in the jitted ops wrapper
-# (``ops.dynamic_index_lookup``) — the kernel owns everything logarithmic.
+# path.  The base tier takes the static lookup's four stages; the sorted
+# delta tier (the device-resident insert buffer, sized by the Lemma 4.1
+# rebuild policy) takes a full-depth probe.  The tombstone mask and two-tier
+# rank arithmetic are O(Q) gathers in the jitted ops wrapper
+# (``ops.dynamic_index_lookup``).
 # ---------------------------------------------------------------------------
 def _full_probe(dk, q, *, nd: int, d_iters: int, right: bool = False):
-    """Full-depth branchless search of the VMEM-resident delta tier (pure
-    jnp on values — shared by the dynamic point and range kernel bodies).
-    The tier is sorted ascending and +inf padded, so both boundaries of a
-    finite query always land within the live prefix (``kv <= q`` is False
-    at every +inf pad)."""
-    dl = jnp.zeros((TQ,), jnp.int32)
-    dh = jnp.full((TQ,), nd, jnp.int32)
-
-    def dbody(_, lh):
-        l, h2 = lh
-        active = h2 - l > 0
-        mid = (l + h2) // 2
+    """Full-depth branchless search of the delta tier (XLA, shared by the
+    dynamic point and range lookups).  The tier is sorted ascending and
+    +inf padded, so both boundaries of a finite query always land within
+    the live prefix (``kv <= q`` is False at every +inf pad)."""
+    l = jnp.zeros(q.shape, jnp.int32)
+    h = jnp.full(q.shape, nd, jnp.int32)
+    for _ in range(d_iters):      # unrolled: no loop carry to type under
+        active = h - l > 0        # shard_map's varying-axes check
+        mid = (l + h) // 2
         kv = jnp.take(dk, jnp.clip(mid, 0, nd - 1))
         below = kv <= q if right else kv < q
-        nl = jnp.where(below, mid + 1, l)
-        nh = jnp.where(below, h2, mid)
-        return (jnp.where(active, nl, l), jnp.where(active, nh, h2))
-
-    dl, _ = jax.lax.fori_loop(0, d_iters, dbody, (dl, dh))
-    return dl
-
-
-def _dynamic_lookup_kernel(root_ref, mat_ref, vec_ref, q_ref, dkeys_ref,
-                           keys_ref, out_ref, dout_ref, lo_ref, hi_ref, *,
-                           n_keys: int, n_leaves: int, lp: int, tile: int,
-                           tile_iters: int, nd: int, d_iters: int,
-                           route_n: int, root_kind: str, leaf_kind: str):
-    j = pl.program_id(1)
-    q = q_ref[...].reshape(TQ)
-
-    @pl.when(j == 0)
-    def _():
-        lo, hi = _route_window(
-            root_ref[...].reshape(ROOT_ROWS, 128),
-            mat_ref[...].reshape(3 * H * lp), vec_ref[...].reshape(8 * lp),
-            q, n_keys=n_keys, n_leaves=n_leaves, lp=lp, route_n=route_n,
-            root_kind=root_kind, leaf_kind=leaf_kind)
-        lo_ref[...] = lo.reshape(lo_ref.shape)
-        hi_ref[...] = hi.reshape(hi_ref.shape)
-        out_ref[...] = hi.reshape(out_ref.shape)
-
-        # ---- delta probe: full-depth search of the VMEM-resident tier ---
-        dl = _full_probe(dkeys_ref[...].reshape(nd), q, nd=nd,
-                         d_iters=d_iters)
-        dout_ref[...] = dl.reshape(dout_ref.shape)
-
-    # ---- base tier: window-clamped search within key tile j -------------
-    _tile_search_merge(keys_ref, q, lo_ref, hi_ref, out_ref, j,
-                       n_keys=n_keys, tile=tile, tile_iters=tile_iters)
+        l, h = (jnp.where(active & below, mid + 1, l),
+                jnp.where(active & ~below, mid, h))
+    return l
 
 
 def pad_delta(delta_keys, dtype=jnp.float32):
@@ -404,115 +417,33 @@ def dynamic_lookup_pallas(queries, root, mat, vec, keys, delta_keys, *,
     frozen routing scale of the dynamic index (defaults to the current key
     count, i.e. static-index behaviour).
     """
-    Q = queries.shape[0]
     S = keys.shape[0]
-    lp = mat.shape[1]
-    q_pad = -(-Q // TQ) * TQ
-    if route_n is None:
-        route_n = S
-    if tile is None:
-        tile = min(TILE_MAX, _pow2ceil(max(S, 128)))
-    assert tile % 128 == 0, "key tile must be a multiple of 128 lanes"
-    s_pad = -(-S // tile) * tile
-    nk = s_pad // tile
-    if iters is None:
-        iters = full_iters(S)
-    tile_iters = min(iters, full_iters(tile))
-
-    dkp = pad_delta(delta_keys)
-    nd = dkp.shape[0]
-    d_iters = full_iters(nd)
-
-    pad1 = lambda a: jnp.pad(a.astype(jnp.float32), (0, q_pad - Q)) \
-        .reshape(-1, 8, TQ // 8)
-    kp = jnp.pad(keys.astype(jnp.float32), (0, s_pad - S),
-                 constant_values=jnp.inf).reshape(nk, 8, tile // 8)
-
-    kern = functools.partial(
-        _dynamic_lookup_kernel, n_keys=S, n_leaves=n_leaves, lp=lp, tile=tile,
-        tile_iters=tile_iters, nd=nd, d_iters=d_iters, route_n=route_n,
-        root_kind=root_kind, leaf_kind=leaf_kind)
-    out, dout = pl.pallas_call(
-        kern,
-        grid=(q_pad // TQ, nk),
-        in_specs=[
-            pl.BlockSpec((ROOT_ROWS, 128), lambda i, j: (0, 0)),      # root
-            pl.BlockSpec((3 * H, lp), lambda i, j: (0, 0)),           # mat
-            pl.BlockSpec((8, lp), lambda i, j: (0, 0)),               # vec
-            pl.BlockSpec((1, 8, TQ // 8), lambda i, j: (i, 0, 0)),    # q
-            pl.BlockSpec((1, 8, nd // 8), lambda i, j: (0, 0, 0)),    # delta
-            pl.BlockSpec((1, 8, tile // 8), lambda i, j: (j, 0, 0)),  # keys
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 8, TQ // 8), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((1, 8, TQ // 8), lambda i, j: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((q_pad // TQ, 8, TQ // 8), jnp.int32),
-            jax.ShapeDtypeStruct((q_pad // TQ, 8, TQ // 8), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((8, TQ // 8), jnp.int32),   # lo window
-                        pltpu.VMEM((8, TQ // 8), jnp.int32)],  # hi window
-        interpret=interpret,
-    )(root, mat, vec, pad1(queries), dkp.reshape(1, 8, nd // 8), kp)
-    return out.reshape(-1)[:Q], dout.reshape(-1)[:Q]
+    q = queries.astype(jnp.float32)
+    lo, hi = _route_window(
+        root, mat.reshape(-1), vec.reshape(-1), q, n_keys=S,
+        n_leaves=n_leaves, lp=mat.shape[1],
+        route_n=S if route_n is None else route_n, root_kind=root_kind,
+        leaf_kind=leaf_kind)
+    (out,) = _window_search((q,), (lo,), (hi,), keys, sides=(False,),
+                           tile=tile, iters=iters, interpret=interpret)
+    dk = pad_delta(delta_keys)
+    nd = dk.shape[0]
+    return out, _full_probe(dk, q, nd=nd, d_iters=full_iters(nd))
 
 
 # ---------------------------------------------------------------------------
-# Fused range kernel: both endpoints of [lo, hi] routed in ONE tile pass.
+# Fused range lookup: both endpoints of [lo, hi] searched in ONE tile pass.
 # The lo endpoint uses the point path's left-bound search; the hi endpoint
 # runs the mirrored right-bound search (first position with key > q, i.e.
-# the rightmost rank under duplicate keys — see _tile_search_merge's
-# ``right`` flag for why the min-merge composes identically).  Each key tile
-# is streamed through VMEM once and searched twice, so a range lookup costs
-# one kernel invocation and the same HBM traffic as a single point lookup.
-# Both candidates are window-clamped and seam-verified by the ops wrapper
-# (``ops.range_lookup``) exactly like the point path.
+# the rightmost rank under duplicate keys — the min-merge composes
+# identically: the first tile containing a key > q yields the winning
+# candidate, tiles whose clipped window is entirely <= q converge to
+# l == thi, and +inf capacity padding compares > q for every finite query).
+# Each key tile is streamed through VMEM once and searched twice, so a range
+# lookup costs one kernel invocation and the same HBM traffic as a single
+# point lookup.  Both candidates are window-clamped and seam-verified by the
+# ops wrapper (``ops.range_lookup``) exactly like the point path.
 # ---------------------------------------------------------------------------
-def _dynamic_range_kernel(root_ref, mat_ref, vec_ref, qlo_ref, qhi_ref,
-                          dkeys_ref, keys_ref,
-                          blo_ref, bhi_ref, dlo_ref, dhi_ref,
-                          llo_ref, lhi_ref, rlo_ref, rhi_ref, *,
-                          n_keys: int, n_leaves: int, lp: int, tile: int,
-                          tile_iters: int, nd: int, d_iters: int,
-                          route_n: int, root_kind: str, leaf_kind: str):
-    j = pl.program_id(1)
-    ql = qlo_ref[...].reshape(TQ)
-    qh = qhi_ref[...].reshape(TQ)
-
-    @pl.when(j == 0)
-    def _():
-        root = root_ref[...].reshape(ROOT_ROWS, 128)
-        mat = mat_ref[...].reshape(3 * H * lp)
-        vec = vec_ref[...].reshape(8 * lp)
-        lo, hi = _route_window(
-            root, mat, vec, ql, n_keys=n_keys, n_leaves=n_leaves, lp=lp,
-            route_n=route_n, root_kind=root_kind, leaf_kind=leaf_kind)
-        llo_ref[...] = lo.reshape(llo_ref.shape)
-        lhi_ref[...] = hi.reshape(lhi_ref.shape)
-        blo_ref[...] = hi.reshape(blo_ref.shape)
-        lo, hi = _route_window(
-            root, mat, vec, qh, n_keys=n_keys, n_leaves=n_leaves, lp=lp,
-            route_n=route_n, root_kind=root_kind, leaf_kind=leaf_kind)
-        rlo_ref[...] = lo.reshape(rlo_ref.shape)
-        rhi_ref[...] = hi.reshape(rhi_ref.shape)
-        bhi_ref[...] = hi.reshape(bhi_ref.shape)
-
-        # ---- delta probes: left bound of lo, right bound of hi ----------
-        dk = dkeys_ref[...].reshape(nd)
-        dlo_ref[...] = _full_probe(dk, ql, nd=nd, d_iters=d_iters) \
-            .reshape(dlo_ref.shape)
-        dhi_ref[...] = _full_probe(dk, qh, nd=nd, d_iters=d_iters,
-                                   right=True).reshape(dhi_ref.shape)
-
-    # ---- base tier: both endpoints searched within key tile j -----------
-    _tile_search_merge(keys_ref, ql, llo_ref, lhi_ref, blo_ref, j,
-                       n_keys=n_keys, tile=tile, tile_iters=tile_iters)
-    _tile_search_merge(keys_ref, qh, rlo_ref, rhi_ref, bhi_ref, j,
-                       n_keys=n_keys, tile=tile, tile_iters=tile_iters,
-                       right=True)
-
-
 def dynamic_range_pallas(q_lo, q_hi, root, mat, vec, keys, delta_keys, *,
                          n_leaves: int, route_n: int | None = None,
                          root_kind: str = "linear",
@@ -527,66 +458,30 @@ def dynamic_range_pallas(q_lo, q_hi, root, mat, vec, keys, delta_keys, *,
     key compares > q_hi, i.e. rightmost rank).  Both endpoints ride the
     same grid pass, so each key tile is fetched from HBM exactly once.
     """
-    Q = q_lo.shape[0]
-    assert q_hi.shape[0] == Q, "endpoint arrays must pair up"
+    assert q_hi.shape[0] == q_lo.shape[0], "endpoint arrays must pair up"
     S = keys.shape[0]
-    lp = mat.shape[1]
-    q_pad = -(-Q // TQ) * TQ
-    if route_n is None:
-        route_n = S
-    if tile is None:
-        tile = min(TILE_MAX, _pow2ceil(max(S, 128)))
-    assert tile % 128 == 0, "key tile must be a multiple of 128 lanes"
-    s_pad = -(-S // tile) * tile
-    nk = s_pad // tile
-    if iters is None:
-        iters = full_iters(S)
-    tile_iters = min(iters, full_iters(tile))
-
-    dkp = pad_delta(delta_keys)
-    nd = dkp.shape[0]
+    ql = q_lo.astype(jnp.float32)
+    qh = q_hi.astype(jnp.float32)
+    win = lambda q: _route_window(
+        root, mat.reshape(-1), vec.reshape(-1), q, n_keys=S,
+        n_leaves=n_leaves, lp=mat.shape[1],
+        route_n=S if route_n is None else route_n, root_kind=root_kind,
+        leaf_kind=leaf_kind)
+    (llo, lhi), (rlo, rhi) = win(ql), win(qh)
+    blo, bhi = _window_search((ql, qh), (llo, rlo), (lhi, rhi), keys,
+                             sides=(False, True), tile=tile, iters=iters,
+                             interpret=interpret)
+    dk = pad_delta(delta_keys)
+    nd = dk.shape[0]
     d_iters = full_iters(nd)
-
-    pad1 = lambda a: jnp.pad(a.astype(jnp.float32), (0, q_pad - Q)) \
-        .reshape(-1, 8, TQ // 8)
-    kp = jnp.pad(keys.astype(jnp.float32), (0, s_pad - S),
-                 constant_values=jnp.inf).reshape(nk, 8, tile // 8)
-
-    kern = functools.partial(
-        _dynamic_range_kernel, n_keys=S, n_leaves=n_leaves, lp=lp, tile=tile,
-        tile_iters=tile_iters, nd=nd, d_iters=d_iters, route_n=route_n,
-        root_kind=root_kind, leaf_kind=leaf_kind)
-    qspec = pl.BlockSpec((1, 8, TQ // 8), lambda i, j: (i, 0, 0))
-    blo, bhi, dlo, dhi = pl.pallas_call(
-        kern,
-        grid=(q_pad // TQ, nk),
-        in_specs=[
-            pl.BlockSpec((ROOT_ROWS, 128), lambda i, j: (0, 0)),      # root
-            pl.BlockSpec((3 * H, lp), lambda i, j: (0, 0)),           # mat
-            pl.BlockSpec((8, lp), lambda i, j: (0, 0)),               # vec
-            qspec,                                                    # q_lo
-            qspec,                                                    # q_hi
-            pl.BlockSpec((1, 8, nd // 8), lambda i, j: (0, 0, 0)),    # delta
-            pl.BlockSpec((1, 8, tile // 8), lambda i, j: (j, 0, 0)),  # keys
-        ],
-        out_specs=[qspec, qspec, qspec, qspec],
-        out_shape=[
-            jax.ShapeDtypeStruct((q_pad // TQ, 8, TQ // 8), jnp.int32)
-            for _ in range(4)
-        ],
-        scratch_shapes=[pltpu.VMEM((8, TQ // 8), jnp.int32)   # lo window x2,
-                        for _ in range(4)],                   # hi window x2
-        interpret=interpret,
-    )(root, mat, vec, pad1(q_lo), pad1(q_hi), dkp.reshape(1, 8, nd // 8), kp)
-    flat = lambda a: a.reshape(-1)[:Q]
-    return flat(blo), flat(bhi), flat(dlo), flat(dhi)
+    return (blo, bhi, _full_probe(dk, ql, nd=nd, d_iters=d_iters),
+            _full_probe(dk, qh, nd=nd, d_iters=d_iters, right=True))
 
 
 # ---------------------------------------------------------------------------
-# RMRT: in-kernel fixed-depth masked descent over the flat node tables (see
-# the module docstring for the pack_rmrt layout), then the same clamped
-# tiled search as the static kernel.  Replaces the XLA masked-descent loop
-# that used to pre-route queries before the kernel.
+# RMRT: fixed-depth masked descent over the flat node tables (see the module
+# docstring for the pack_rmrt layout), then the same clamped tiled search
+# as the static lookup.
 # ---------------------------------------------------------------------------
 def pack_rmrt(kind: str, params, is_leaf, child_base, y_start, y_end,
               err_lo, err_hi):
@@ -622,10 +517,9 @@ def pack_rmrt(kind: str, params, is_leaf, child_base, y_start, y_end,
 
 def _rmrt_route_window(mat, vec, q, *, n_keys: int, npad: int, fanout: int,
                        depth: int, kind: str):
-    """Stages 1-3 of the RMRT lookup (pure jnp on values — shared by the
-    kernel body; the oracle in ``kernels.ref`` reimplements it): depth-D
-    masked descent over the VMEM-resident node tables, then the leaf's
-    error-bound window clamped to ``n_keys``."""
+    """Stages 1-3 of the RMRT lookup (XLA; the oracle in ``kernels.ref``
+    reimplements it): depth-D masked descent over the flattened node
+    tables, then the leaf's error-bound window clamped to ``n_keys``."""
     row = lambda flat, r, idx: jnp.take(flat, idx + r * npad)
 
     def predict(node):
@@ -657,69 +551,17 @@ def _rmrt_route_window(mat, vec, q, *, n_keys: int, npad: int, fanout: int,
     return lo, hi
 
 
-def _rmrt_lookup_kernel(mat_ref, vec_ref, q_ref, keys_ref, out_ref,
-                        lo_ref, hi_ref, *,
-                        n_keys: int, npad: int, fanout: int, depth: int,
-                        tile: int, tile_iters: int, kind: str):
-    j = pl.program_id(1)
-    q = q_ref[...].reshape(TQ)
-
-    @pl.when(j == 0)
-    def _():
-        lo, hi = _rmrt_route_window(
-            mat_ref[...].reshape(3 * H * npad), vec_ref[...].reshape(8 * npad),
-            q, n_keys=n_keys, npad=npad, fanout=fanout, depth=depth,
-            kind=kind)
-        lo_ref[...] = lo.reshape(lo_ref.shape)
-        hi_ref[...] = hi.reshape(hi_ref.shape)
-        out_ref[...] = hi.reshape(out_ref.shape)
-
-    _tile_search_merge(keys_ref, q, lo_ref, hi_ref, out_ref, j,
-                       n_keys=n_keys, tile=tile, tile_iters=tile_iters)
-
-
 def rmrt_lookup_pallas(queries, mat, vec, keys, *, fanout: int, depth: int,
                        kind: str = "linear", iters: int | None = None,
                        tile: int | None = None, interpret: bool = True):
     """Positions (left boundary, window-clamped) of ``queries`` in ``keys``
-    under the RMRT: the whole depth-``depth`` descent runs in-kernel over
-    the packed node tables (``pack_rmrt``), then the error-window-clamped
-    tiled search — one kernel, no XLA pre-routing.
+    under the RMRT: the depth-``depth`` descent over the packed node tables
+    (``pack_rmrt``), then the error-window-clamped tiled search kernel.
     """
-    Q = queries.shape[0]
-    S = keys.shape[0]
-    npad = mat.shape[1]
-    q_pad = -(-Q // TQ) * TQ
-    if tile is None:
-        tile = min(TILE_MAX, _pow2ceil(max(S, 128)))
-    assert tile % 128 == 0, "key tile must be a multiple of 128 lanes"
-    s_pad = -(-S // tile) * tile
-    nk = s_pad // tile
-    if iters is None:
-        iters = full_iters(S)
-    tile_iters = min(iters, full_iters(tile))
-
-    pad1 = lambda a: jnp.pad(a.astype(jnp.float32), (0, q_pad - Q)) \
-        .reshape(-1, 8, TQ // 8)
-    kp = jnp.pad(keys.astype(jnp.float32), (0, s_pad - S),
-                 constant_values=jnp.inf).reshape(nk, 8, tile // 8)
-
-    kern = functools.partial(
-        _rmrt_lookup_kernel, n_keys=S, npad=npad, fanout=fanout, depth=depth,
-        tile=tile, tile_iters=tile_iters, kind=kind)
-    out = pl.pallas_call(
-        kern,
-        grid=(q_pad // TQ, nk),
-        in_specs=[
-            pl.BlockSpec((3 * H, npad), lambda i, j: (0, 0)),         # mat
-            pl.BlockSpec((8, npad), lambda i, j: (0, 0)),             # vec
-            pl.BlockSpec((1, 8, TQ // 8), lambda i, j: (i, 0, 0)),    # q
-            pl.BlockSpec((1, 8, tile // 8), lambda i, j: (j, 0, 0)),  # keys
-        ],
-        out_specs=pl.BlockSpec((1, 8, TQ // 8), lambda i, j: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((q_pad // TQ, 8, TQ // 8), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((8, TQ // 8), jnp.int32),   # lo window
-                        pltpu.VMEM((8, TQ // 8), jnp.int32)],  # hi window
-        interpret=interpret,
-    )(mat, vec, pad1(queries), kp)
-    return out.reshape(-1)[:Q]
+    q = queries.astype(jnp.float32)
+    lo, hi = _rmrt_route_window(
+        mat.reshape(-1), vec.reshape(-1), q, n_keys=keys.shape[0],
+        npad=mat.shape[1], fanout=fanout, depth=depth, kind=kind)
+    (out,) = _window_search((q,), (lo,), (hi,), keys, sides=(False,),
+                           tile=tile, iters=iters, interpret=interpret)
+    return out

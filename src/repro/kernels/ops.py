@@ -128,7 +128,12 @@ def _seam_fix(r, kf, qf, seam_budget: int, right: bool = False):
     budget = min(seam_budget, qf.shape[0])
 
     def _sparse(_):
-        idx = jnp.nonzero(~valid, size=budget, fill_value=0)[0]
+        # jnp.nonzero(~valid, size=budget, fill_value=0) in int32: its x64
+        # default (an int64 cumsum) does not compile for the TPU.
+        bad = (~valid).astype(jnp.int32)
+        slot = jnp.where(valid, budget, jnp.cumsum(bad, dtype=jnp.int32) - 1)
+        idx = jnp.zeros((budget,), jnp.int32).at[slot].set(
+            jnp.arange(bad.shape[0], dtype=jnp.int32), mode="drop")
         sub = jnp.searchsorted(kf, qf[idx], side=side).astype(r.dtype)
         return r.at[idx].set(jnp.where(valid[idx], r[idx], sub))
 
@@ -159,7 +164,7 @@ def rmrt_lookup(queries, mat, vec, keys, *, fanout: int, depth: int,
                 kind: str = "linear", iters: int | None = None,
                 tile: int | None = None, interpret: bool | None = None,
                 seam_budget: int = 1024):
-    """Fused RMRT serving lookup: in-kernel fixed-depth descent over the
+    """Fused RMRT serving lookup: fixed-depth descent over the
     packed node tables (lookup.pack_rmrt) + error-window-clamped tiled
     search, with the same XLA-side sparse seam verification as
     :func:`index_lookup`.
@@ -202,8 +207,9 @@ def dynamic_index_lookup(queries, root, mat, vec, keys, base_dead, base_psum,
                          interpret: bool | None = None,
                          seam_budget: int = 1024):
     """Fused two-tier serving find for the dynamic index: one Pallas kernel
-    (base window search + delta probe), then O(Q) jitted gathers for the
-    tombstone mask and the two-tier live rank.  Zero per-query host Python.
+    (base window search) and the full-depth delta probe, then O(Q) jitted
+    gathers for the tombstone mask and the two-tier live rank.  Zero
+    per-query host Python.
 
     ``keys``/``delta_keys`` are the sorted base/delta tiers (delta +inf
     padded to its storage capacity); ``*_dead`` the tombstone bitmaps and
@@ -260,7 +266,7 @@ def _dynamic_lookup_jit(queries, root, mat, vec, keys, base_dead, base_psum,
     kf = keys.astype(jnp.float32)
     qf = queries.astype(jnp.float32)
     # Base tier: seam-verify the window-clamped positions, then tombstone
-    # mask.  The delta probe ran at full depth over the whole (VMEM-sized)
+    # mask.  The delta probe ran at full depth over the whole (small)
     # tier, so its boundary is already exact — no seam pass.  A hit is any
     # *live* entry in the equal-key run [left, right): count live slots via
     # the tombstone prefix sums (robust to partially tombstoned duplicate

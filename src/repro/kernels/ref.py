@@ -196,7 +196,7 @@ def dynamic_lookup_ref(queries, root, mat, vec, keys, delta_keys, *,
     """Oracle for lookup.dynamic_lookup_pallas: (base_pos, delta_pos).
     The base tier is exactly :func:`lookup_ref` with the frozen ``route_n``
     routing scale (one oracle — no drift between the static and dynamic
-    base-search semantics); the delta probe mirrors the kernel's full-depth
+    base-search semantics); the delta probe mirrors the lookup's full-depth
     search of the +inf-padded tier.  Bit-identical in interpret mode."""
     from . import lookup as _lk
 

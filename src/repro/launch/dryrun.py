@@ -204,8 +204,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):     # jax 0.4.x returns [dict], newer a dict
-        cost = cost[0]
     hlo_text = compiled.as_text()
     coll = parse_collectives(hlo_text)
 

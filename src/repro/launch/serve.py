@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 import repro  # noqa: F401
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_arch
 from repro.configs.reduced import reduce_cfg
 from repro.launch.mesh import make_smoke_mesh
@@ -94,6 +95,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args()
+    use_compile_cache()
     serve(args.arch, reduced=args.reduced, requests=args.requests,
           prompt_len=args.prompt_len, new_tokens=args.new_tokens)
 

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro  # noqa: F401
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_arch
 from repro.configs.reduced import reduce_cfg
 from repro.data.indexed_dataset import synthetic_token_stream
@@ -92,6 +93,7 @@ def main():
     ap.add_argument("--n-layers", type=int, default=None)
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    use_compile_cache()
     train(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
           lr=args.lr, reduced=args.reduced, ckpt_dir=args.ckpt_dir,
           d_model=args.d_model, n_layers=args.n_layers)

@@ -17,67 +17,10 @@ no-ops. Conventions:
 """
 from __future__ import annotations
 
-import enum
-import inspect
-
 import jax
-import jax.numpy as jnp
 
 POD, FSDP, TP = "pod", "data", "model"
 
-
-# ---------------------------------------------------------------------------
-# jax 0.4.x compat shim.
-#
-# The pinned jax (0.4.37) predates several APIs this codebase targets:
-#   * jax.shard_map            (only jax.experimental.shard_map, check_rep)
-#   * jax.sharding.AxisType / jax.make_mesh(axis_types=...)  (explicit meshes)
-#   * jax.typeof(...).vma + jax.lax.pcast  (varying-manual-axes typing)
-#   * jax.lax.axis_size
-#
-# Installed once at import (repro/__init__ imports this module). On the old
-# API there is no vma type system, so pcast degrades to identity and
-# shard_map runs with check_rep=False — the collectives in this codebase are
-# all explicit, so forward/backward semantics are unchanged; only the static
-# replication checking is lost.
-# ---------------------------------------------------------------------------
-class _PlainAval:
-    vma: frozenset = frozenset()
-
-
-def _install_jax_compat() -> None:
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _esm
-
-        def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-            return _esm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                        check_rep=False)
-
-        jax.shard_map = shard_map
-    if not hasattr(jax.sharding, "AxisType"):
-        class AxisType(enum.Enum):
-            Auto = "auto"
-            Explicit = "explicit"
-            Manual = "manual"
-
-        jax.sharding.AxisType = AxisType
-    if "axis_types" not in inspect.signature(jax.make_mesh).parameters:
-        _make_mesh = jax.make_mesh
-
-        def make_mesh(axis_shapes, axis_names, *, axis_types=None, **kw):
-            return _make_mesh(axis_shapes, axis_names, **kw)
-
-        jax.make_mesh = make_mesh
-    if not hasattr(jax, "typeof"):
-        jax.typeof = lambda v: _PlainAval
-    if not hasattr(jax.lax, "pcast"):
-        jax.lax.pcast = lambda x, axes, to=None: x
-    if not hasattr(jax.lax, "axis_size"):
-        jax.lax.axis_size = lambda name: jax.lax.psum(
-            jnp.ones((), jnp.int32), name)
-
-
-_install_jax_compat()
 
 # Batch-carrying axes. The production single-pod mesh is (data, model) with
 # no pod axis, so this is configured per step-factory (set_batch_axes runs

@@ -260,9 +260,9 @@ class TenantPack:
         """One tenant's (S, ...) slice set padded to the cross-tenant
         geometry — the unit of incremental tenant restacking."""
         L, lt = self.n_leaves, t.n_leaves
-        padv = lambda a, c, v: jnp.pad(
+        padv = lambda a, c, v: a if a.shape[1] == c else jnp.pad(
             a, ((0, 0), (0, c - a.shape[1])), constant_values=v)
-        pade = lambda a, c: jnp.pad(
+        pade = lambda a, c: a if a.shape[1] == c else jnp.pad(
             a, ((0, 0), (0, c - a.shape[1])) + ((0, 0),) * (a.ndim - 2),
             mode="edge")
         row = dict(
@@ -305,7 +305,10 @@ class TenantPack:
             rows = [self._tenant_row(t, st, bcap, dcap)
                     for t, st in zip(self.tenants, sts, strict=True)]
             stack = lambda k: jnp.stack([r[k] for r in rows])
-            self._st = {k: stack(k) for k in self._STACK_KEYS}
+            lay = self.tenants[0].shard_sharding(lead=1)
+            self._st = {k: stack(k) if k == "splits"
+                        else jax.device_put(stack(k), lay)
+                        for k in self._STACK_KEYS}
             tmap = lambda k: jax.tree.map(lambda *a: jnp.stack(a),
                                           *[r[k] for r in rows])
             self._st["root"] = tmap("root")
@@ -341,28 +344,38 @@ class TenantPack:
         return self._st
 
     # -- dispatch ----------------------------------------------------------
+    def dispatch(self, kind: str, qmat) -> tuple:
+        """``(program, args)`` of one stacked dispatch, for :meth:`find`
+        (``kind="find"``) and :meth:`find_range` (``"range"``): calling
+        ``program(*args)`` runs it, ``program.lower(*args)`` shows what it
+        compiles to."""
+        st = self._refresh()
+        qmat = jnp.asarray(qmat, jnp.float64)
+        T, w = qmat.shape
+        per = self.n_shards * (2 if kind == "range" else 1)
+        if T != self.n_tenants or w % per:
+            raise ValueError(f"bad {kind} matrix {qmat.shape}: want "
+                             f"({self.n_tenants}, k*{per})")
+        make = dist_mod._tenant_stacked_range_fn if kind == "range" \
+            else dist_mod._tenant_stacked_find_fn
+        fn = make(self.mesh, self.axis, n_tenants=self.n_tenants,
+                  n_leaves=self.n_leaves, leaf_kind=self.leaf_kind,
+                  iters=st["iters"], use_kernel=self.use_kernel,
+                  interpret=self.interpret)
+        tables = (st["kroot"], st["kmat"], st["kvec"]) if self.use_kernel \
+            else (st["root"], st["leaves"], st["err_lo"], st["err_hi"])
+        return fn, (st["splits"], st["offs"], st["route_n"], st["base"],
+                    st["bdead"], st["bpsum"], st["dk"], st["ddead"],
+                    st["dpsum"], tables, qmat)
+
     def find(self, qmat) -> tuple[Array, Array]:
         """One stacked dispatch: ``qmat`` is (n_tenants, qcap) f64 with
         finite pads (qcap a multiple of the shard count; callers pad to
         ``capacity_class`` widths to stay on the warm trace).  Returns
         (found, rank) as (n_tenants, qcap) device arrays — asynchronous,
         so callers can overlap the next batch's staging."""
-        st = self._refresh()
-        qmat = jnp.asarray(qmat, jnp.float64)
-        T, qcap = qmat.shape
-        if T != self.n_tenants or qcap % self.n_shards:
-            raise ValueError(f"bad query matrix {qmat.shape}: want "
-                             f"({self.n_tenants}, k*{self.n_shards})")
-        fn = dist_mod._tenant_stacked_find_fn(
-            self.mesh, self.axis, n_tenants=self.n_tenants,
-            n_leaves=self.n_leaves, leaf_kind=self.leaf_kind,
-            iters=st["iters"], use_kernel=self.use_kernel,
-            interpret=self.interpret)
-        tables = (st["kroot"], st["kmat"], st["kvec"]) if self.use_kernel \
-            else (st["root"], st["leaves"], st["err_lo"], st["err_hi"])
-        return fn(st["splits"], st["offs"], st["route_n"], st["base"],
-                  st["bdead"], st["bpsum"], st["dk"], st["ddead"],
-                  st["dpsum"], tables, qmat)
+        fn, args = self.dispatch("find", qmat)
+        return fn(*args)
 
     def find_range(self, rmat) -> tuple[Array, Array]:
         """One stacked range dispatch: ``rmat`` is (n_tenants, 2 * rcap)
@@ -370,23 +383,9 @@ class TenantPack:
         of the shard count, finite pads).  Returns (rank_lo, rank_hi) as
         (n_tenants, rcap) device arrays with rank_hi clamped to rank_lo —
         same asynchrony contract as :meth:`find`."""
-        st = self._refresh()
-        rmat = jnp.asarray(rmat, jnp.float64)
-        T, w = rmat.shape
-        if T != self.n_tenants or w % (2 * self.n_shards):
-            raise ValueError(f"bad range matrix {rmat.shape}: want "
-                             f"({self.n_tenants}, 2*k*{self.n_shards})")
-        fn = dist_mod._tenant_stacked_range_fn(
-            self.mesh, self.axis, n_tenants=self.n_tenants,
-            n_leaves=self.n_leaves, leaf_kind=self.leaf_kind,
-            iters=st["iters"], use_kernel=self.use_kernel,
-            interpret=self.interpret)
-        tables = (st["kroot"], st["kmat"], st["kvec"]) if self.use_kernel \
-            else (st["root"], st["leaves"], st["err_lo"], st["err_hi"])
-        rl, rr = fn(st["splits"], st["offs"], st["route_n"], st["base"],
-                    st["bdead"], st["bpsum"], st["dk"], st["ddead"],
-                    st["dpsum"], tables, rmat)
-        rcap = w // 2
+        fn, args = self.dispatch("range", rmat)
+        rl, rr = fn(*args)
+        rcap = rmat.shape[1] // 2
         rank_lo = rl[:, :rcap]
         return rank_lo, jnp.maximum(rr[:, rcap:], rank_lo)
 

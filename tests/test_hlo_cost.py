@@ -22,8 +22,6 @@ def test_scan_trip_counts():
     assert abs(got - want) / want < 0.01, (got, want)
     # and confirm XLA's own number misses the trip count
     xla = c.cost_analysis()
-    if isinstance(xla, list):   # jax 0.4.x returns [dict], newer a dict
-        xla = xla[0]
     assert xla["flops"] < want / 4
 
 

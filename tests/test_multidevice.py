@@ -9,7 +9,6 @@ import os
 import subprocess
 import sys
 
-import jax
 import pytest
 
 _SCRIPT = r"""
@@ -122,12 +121,6 @@ print(f"NUMERIC_OK loss {l1:.4f}~{l4:.4f} gnorm {g1:.3f}~{g4:.3f}")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    tuple(int(x) for x in jax.__version__.split(".")[:2]) < (0, 6),
-    reason="needs the real pcast/vma machinery (jax >= 0.6): the 0.4.x "
-           "compat shim runs shard_map with check_rep=False, which loses "
-           "the replication typing this equivalence rests on (ROADMAP "
-           "'True vma typing')")
 def test_spmd_numeric_equivalence():
     """Loss/grad-norm/updated params agree between the (1,1,1) and (1,2,2)
     meshes — validates the manual-SPMD collective algebra (FSDP gathers,

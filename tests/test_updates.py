@@ -676,6 +676,52 @@ def test_flush_delta_merges_and_localizes():
     assert d.insertion_headroom > 0
 
 
+def test_host_refit_matches_in_place_refit(monkeypatch):
+    """On an accelerator, builds and leaf refits run on the host CPU backend
+    and move back (``updates._host_device``).  Forcing that route here must
+    leave the rebuilt and flushed state bit-identical to the in-place one."""
+    import jax
+    base = _f32_keys(30_000, seed=71)
+    ins = np.setdiff1d(_f32_keys(6_000, seed=72), base)
+    extra = np.setdiff1d(_f32_keys(400, seed=73), np.concatenate([base, ins]))
+
+    def churned():
+        d = DynamicRMI.build(jnp.asarray(base), eps=0.9, n_leaves=64,
+                             kind="linear")
+        d.insert_batch(ins)
+        d.delete_batch(base[::7])
+        d.insert_batch(extra)
+        d.flush_delta()
+        return d
+
+    want = churned()
+    monkeypatch.setattr(updates_mod, "_host_device",
+                        lambda: jax.devices("cpu")[0])
+    got = churned()
+    assert got.rebuilds == want.rebuilds > 0
+    for a, b in [(got.index.keys, want.index.keys),
+                 (got.index.err_lo, want.index.err_lo),
+                 (got.index.err_hi, want.index.err_hi),
+                 (got.base_dead, want.base_dead),
+                 *zip(got.index.leaves, want.index.leaves, strict=True)]:
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(got.budget, want.budget)
+    _assert_find_exact(got, np.concatenate([base[:300], ins[:300], extra]))
+
+
+def test_host_device_requires_a_cpu_backend(monkeypatch):
+    """An accelerator process that hides the CPU backend gets an error
+    naming the fix, not a build compiled for the accelerator."""
+    import jax
+
+    def devices(backend=None):
+        raise RuntimeError(f"unknown backend {backend}")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices", devices)
+    with pytest.raises(RuntimeError, match="JAX_PLATFORMS"):
+        updates_mod._host_device()
+
+
 def test_maintenance_stats_surface():
     from repro.serve.kvcache import DynamicPageTable, PagedKVCache
     cache = PagedKVCache(n_pages=64, page_size=8, n_kv_heads=1, head_dim=4,
