@@ -1,0 +1,21 @@
+"""Published peaks of each device the benchmark may run on, keyed by JAX's
+``device_kind``.  A device that is not here is an error, not a default."""
+from __future__ import annotations
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e" (system architecture page).
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud, TPU v5e",
+    },
+}
+
+
+def peaks(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; add them "
+                       "to bench/peaks.py with their source")
+    return PEAKS[device_kind]
