@@ -124,7 +124,7 @@ def _drive(frontend, fresh, workload: str, rate: float, duration: float,
         "achieved_qps": float(len(reqs) / span),
         "p50_ms": q(50), "p99_ms": q(99), "p999_ms": q(99.9),
         "detail": f"reqs={len(reqs)} batches={st.batches} "
-                  f"pad_frac={st.pad_fraction:.2f} "
+                  f"padded_slots={st.padded_slots} "
                   f"qcaps={sorted(st.qcaps)}",
     }
 

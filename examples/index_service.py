@@ -79,7 +79,8 @@ with BatchingFrontend(tenants,
           f"p99={np.percentile(lats, 99):.1f}ms")
     print(f"  {st.batches} stacked dispatches over "
           f"{fe.pack.n_tenants} tenants x 4 shards, capacity classes "
-          f"{sorted(st.qcaps)}, pad fraction {st.pad_fraction:.0%}")
+          f"{sorted(st.qcaps)}, {st.padded_slots} pad lanes for "
+          f"{st.queries} keys")
 
 # --- indexed data pipeline --------------------------------------------------
 ds = IndexedDataset.create(eps=0.9, kind="linear", n_leaves=128)

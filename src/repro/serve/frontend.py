@@ -32,7 +32,18 @@ Pipeline (the latency-budget / capacity-class contract)::
   flight; while batch k executes on device, the loop coalesces, stages
   (``jax.device_put``) and dispatches batch k+1, so the device never
   idles between batches.  Results resolve (one host sync per batch) and
-  scatter back to each caller's future.
+  scatter back to each caller's future.  The dispatch returns at once; the
+  loop then blocks in ``_resolve``'s host sync on the batch ahead.  With
+  the device busy, a batch therefore waits one device period behind the
+  batch ahead before its own period runs.
+* **Spans**: each step of the loop is a host span on the profiler's clock
+  (``serve.queued`` per request, ``serve.collect``,
+  ``serve.apply_updates``, ``serve.stage`` with ``serve.refresh``,
+  ``serve.put`` and ``serve.enqueue`` inside, ``serve.inflight`` per
+  batch, ``serve.resolve`` with ``serve.device_wait`` inside,
+  ``serve.maintain``, ``serve.gc`` per collector pause while a frontend
+  runs).  A span costs about a microsecond, and records nothing while no
+  trace runs.
 * **Find/update interleaving**: insert/delete requests coalesce into the
   same batches; they apply *before* the batch's finds dispatch (finds
   observe every update coalesced with them).  Mutations ride the PR 5
@@ -64,14 +75,17 @@ Pipeline (the latency-budget / capacity-class contract)::
 """
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from ..core import distributed as dist_mod
 from ..core.paths import resolve_path
@@ -80,13 +94,53 @@ from ..kernels.lookup import capacity_class, pad_packed_leaves
 Array = jax.Array
 
 
+def _open_span(name: str) -> TraceAnnotation:
+    """Enter a host span that ends where another call site, possibly on
+    another thread, calls its ``__exit__(None, None, None)``.  The
+    profiler records it on the exiting thread's line with the entering
+    call's start time; entered while no trace runs, it records nothing."""
+    span = TraceAnnotation(name)
+    span.__enter__()
+    return span
+
+
+_gc_open: list = []             # the collector pause being recorded
+_gc_lock = threading.Lock()
+_gc_users = 0                   # frontends running
+
+
+def _gc_span(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: one ``serve.gc`` span per collector pause, on
+    the thread that collected."""
+    if phase == "start":
+        _gc_open.append(_open_span("serve.gc"))
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def _hook_gc(running: bool) -> None:
+    """Keep ``_gc_span`` in ``gc.callbacks`` while any frontend runs, so
+    collections outside serving are not labelled ``serve.gc``."""
+    global _gc_users
+    with _gc_lock:
+        _gc_users += 1 if running else -1
+        if running and _gc_users == 1:
+            gc.callbacks.append(_gc_span)
+        elif not running and _gc_users == 0:
+            gc.callbacks.remove(_gc_span)
+
+
 @dataclass
 class ServeConfig:
     """Front-end knobs (see module docstring for the contract)."""
     latency_budget_s: float = 2e-3    # max coalesce wait from oldest request
     max_batch: int = 4096             # early-cut key-count cap per batch
     batch_floor: int = 128            # capacity-class floor for query rows
-    pipeline_depth: int = 2           # batches in flight (double-buffered)
+    # Batches in flight.  At 2 the device never idles between batches, but
+    # where it is busy a batch is cut while the one ahead still runs and
+    # waits a whole device period behind it (a v5e trace of 2x10^8 f64
+    # keys at 1,600 requests/s: 50 ms in flight, two 25 ms periods).
+    pipeline_depth: int = 2
 
 
 REQUEST_KINDS = ("find", "range", "insert", "delete")
@@ -110,7 +164,7 @@ class Request:
         endpoint arrays must pair up.
     """
     __slots__ = ("tenant", "kind", "keys", "arrival", "done_at", "found",
-                 "rank", "rank_lo", "rank_hi", "error", "_event")
+                 "rank", "rank_lo", "rank_hi", "error", "_event", "_queued")
 
     def __init__(self, tenant: int, kind: str, keys,
                  arrival: float | None = None):
@@ -141,6 +195,7 @@ class Request:
         self.rank_hi = None
         self.error = None
         self._event = threading.Event()
+        self._queued = None       # the open serve.queued span
 
     def done(self) -> bool:
         return self._event.is_set()
@@ -234,7 +289,6 @@ class TenantPack:
         self._st: dict | None = None
         self._geom = None
         self._fps: list | None = None     # per-tenant identity fingerprints
-        self.pack_full = 0                # cold tenant-stack assemblies
         self.pack_rows = 0                # tenant rows rewritten in place
 
     @property
@@ -292,6 +346,7 @@ class TenantPack:
     _STACK_KEYS = ("splits", "offs", "route_n", "base", "bdead", "bpsum",
                    "dk", "ddead", "dpsum", "err_lo", "err_hi")
 
+    @partial(annotate_function, name="serve.refresh")
     def _refresh(self) -> dict:
         sts = [t._stacked() for t in self.tenants]
         if self.use_kernel:
@@ -317,7 +372,6 @@ class TenantPack:
                 for k in ("kroot", "kmat", "kvec"):
                     self._st[k] = stack(k)
             self._geom = geom
-            self.pack_full += 1
         else:
             stale = [i for i, fp in enumerate(fps)
                      if not all(a is b
@@ -373,9 +427,11 @@ class TenantPack:
         finite pads (qcap a multiple of the shard count; callers pad to
         ``capacity_class`` widths to stay on the warm trace).  Returns
         (found, rank) as (n_tenants, qcap) device arrays — asynchronous,
-        so callers can overlap the next batch's staging."""
+        so callers can overlap the next batch's staging (``serve.enqueue``
+        spans the call)."""
         fn, args = self.dispatch("find", qmat)
-        return fn(*args)
+        with TraceAnnotation("serve.enqueue"):
+            return fn(*args)
 
     def find_range(self, rmat) -> tuple[Array, Array]:
         """One stacked range dispatch: ``rmat`` is (n_tenants, 2 * rcap)
@@ -384,7 +440,8 @@ class TenantPack:
         (n_tenants, rcap) device arrays with rank_hi clamped to rank_lo —
         same asynchrony contract as :meth:`find`."""
         fn, args = self.dispatch("range", rmat)
-        rl, rr = fn(*args)
+        with TraceAnnotation("serve.enqueue"):
+            rl, rr = fn(*args)
         rcap = rmat.shape[1] // 2
         rank_lo = rl[:, :rcap]
         return rank_lo, jnp.maximum(rr[:, rcap:], rank_lo)
@@ -396,23 +453,22 @@ class FrontendStats:
     queries: int = 0              # live find keys served
     ranges: int = 0               # live range pairs served
     updates: int = 0              # insert/delete keys applied
-    swaps: int = 0                # drift-maintenance pool hot-swaps
     padded_slots: int = 0         # pad lanes dispatched (wasted work)
     qcaps: set = field(default_factory=set)   # capacity classes seen
 
-    @property
-    def pad_fraction(self) -> float:
-        tot = self.queries + 2 * self.ranges + self.padded_slots
-        return self.padded_slots / tot if tot else 0.0
-
 
 class _InFlight:
-    __slots__ = ("found", "rank", "plan", "rank_lo", "rank_hi", "rplan")
+    """One dispatched batch awaiting resolution; ``span`` is its open
+    ``serve.inflight`` span, from the program call returning to the last
+    caller woken."""
+    __slots__ = ("found", "rank", "plan", "rank_lo", "rank_hi", "rplan",
+                 "span")
 
     def __init__(self, found, rank, plan, rank_lo=None, rank_hi=None,
                  rplan=()):
         self.found, self.rank, self.plan = found, rank, plan
         self.rank_lo, self.rank_hi, self.rplan = rank_lo, rank_hi, rplan
+        self.span = _open_span("serve.inflight")
 
 
 class BatchingFrontend:
@@ -440,6 +496,7 @@ class BatchingFrontend:
     def start(self) -> "BatchingFrontend":
         if self._thread is not None:
             raise RuntimeError("frontend already started")
+        _hook_gc(True)
         self._stop = False
         self._thread = threading.Thread(target=self._loop,
                                         name="serve-frontend", daemon=True)
@@ -453,6 +510,7 @@ class BatchingFrontend:
         if self._thread is not None:
             self._thread.join()
             self._thread = None
+            _hook_gc(False)
 
     __enter__ = start
 
@@ -487,6 +545,7 @@ class BatchingFrontend:
             raise ValueError(f"unknown tenant {request.tenant}")
         if request.arrival is None:
             request.arrival = self.clock()
+        request._queued = _open_span("serve.queued")
         with self._cond:
             self.batcher.offer(request)
             self._cond.notify_all()
@@ -523,10 +582,14 @@ class BatchingFrontend:
         return self.submit_range(tenant, lo_keys, hi_keys).result(timeout)
 
     # -- the serving loop --------------------------------------------------
+    # Each step is a host span on the profiler's clock (``serve.*``), so a
+    # trace sets the dispatcher thread's work beside the device's ops.
+    @partial(annotate_function, name="serve.collect")
     def _collect(self) -> list | None:
         """Block for the next batch: wait for a first request, then
         coalesce until the batcher's deadline (or size cap).  Returns None
-        on shutdown with nothing pending."""
+        on shutdown with nothing pending.  Closes each taken request's
+        ``serve.queued`` span."""
         with self._cond:
             while not len(self.batcher):
                 if self._stop:
@@ -535,8 +598,12 @@ class BatchingFrontend:
             while not self._stop and not self.batcher.ready():
                 dl = self.batcher.deadline()
                 self._cond.wait(timeout=max(dl - self.clock(), 0.0))
-            return self.batcher.cut()
+            batch = self.batcher.cut()
+        for req in batch:
+            req._queued.__exit__(None, None, None)
+        return batch
 
+    @partial(annotate_function, name="serve.apply_updates")
     def _apply_updates(self, batch: list) -> None:
         """Mutations coalesced into this batch apply before its finds
         dispatch — each tenant's dirty-row slice cache (and the tenant
@@ -557,77 +624,97 @@ class BatchingFrontend:
             req._event.set()
 
     def _dispatch(self, batch: list) -> _InFlight | None:
+        """Stage the batch's reads and call their stacked programs, in one
+        ``serve.stage`` span; None for a batch of updates alone.  The
+        staging stays in this frame: each call level on the dispatcher's
+        stack slows the tracing and lowering of every program a first
+        call compiles (one more level cost the set-up's warmup 1.2 s of
+        15 s on a v5e host)."""
         finds = [r for r in batch if r.kind == "find"]
         rngs = [r for r in batch if r.kind == "range"]
         if not finds and not rngs:
             return None
         found = rank = rlo = rhi = None
         plan, rplan = [], []            # (req, tenant, start, stop)
-        self.stats.batches += 1
-        if finds:
-            counts = [0] * self.pack.n_tenants
-            for r in finds:
-                t = r.tenant
-                plan.append((r, t, counts[t], counts[t] + r.keys.size))
-                counts[t] += r.keys.size
-            qcap = capacity_class(max(counts), self.config.batch_floor)
-            qcap = max(qcap, self.pack.n_shards)
-            qmat = np.zeros((self.pack.n_tenants, qcap), np.float64)
-            for r, t, a, b in plan:
-                qmat[t, a:b] = r.keys
-            live = sum(counts)
-            self.stats.queries += live
-            self.stats.padded_slots += qmat.size - live
-            self.stats.qcaps.add(qcap)
-            # Stage host->device explicitly, then dispatch asynchronously:
-            # with pipeline_depth > 1 this batch's transfer and compute
-            # overlap the previous batch's compute and the next batch's
-            # coalescing.
-            found, rank = self.pack.find(jax.device_put(qmat))
-        if rngs:
-            # Ranges ride their own [lo block | hi block] matrix with an
-            # independent capacity class (range traffic is usually far
-            # sparser than point traffic — padding one to the other's
-            # width would double the wasted lanes).
-            rcounts = [0] * self.pack.n_tenants
-            for r in rngs:
-                t = r.tenant
-                n = r.keys.shape[1]
-                rplan.append((r, t, rcounts[t], rcounts[t] + n))
-                rcounts[t] += n
-            rcap = capacity_class(max(rcounts), self.config.batch_floor)
-            rcap = max(rcap, self.pack.n_shards)
-            rmat = np.zeros((self.pack.n_tenants, 2 * rcap), np.float64)
-            for r, t, a, b in rplan:
-                rmat[t, a:b] = r.keys[0]
-                rmat[t, rcap + a:rcap + b] = r.keys[1]
-            rlive = sum(rcounts)
-            self.stats.ranges += rlive
-            self.stats.padded_slots += rmat.size - 2 * rlive
-            self.stats.qcaps.add(rcap)
-            rlo, rhi = self.pack.find_range(jax.device_put(rmat))
+        with TraceAnnotation("serve.stage"):
+            self.stats.batches += 1
+            if finds:
+                counts = [0] * self.pack.n_tenants
+                for r in finds:
+                    t = r.tenant
+                    plan.append((r, t, counts[t], counts[t] + r.keys.size))
+                    counts[t] += r.keys.size
+                qcap = capacity_class(max(counts), self.config.batch_floor)
+                qcap = max(qcap, self.pack.n_shards)
+                qmat = np.zeros((self.pack.n_tenants, qcap), np.float64)
+                for r, t, a, b in plan:
+                    qmat[t, a:b] = r.keys
+                live = sum(counts)
+                self.stats.queries += live
+                self.stats.padded_slots += qmat.size - live
+                self.stats.qcaps.add(qcap)
+                # Stage host->device explicitly, then dispatch
+                # asynchronously: the calls return in about a millisecond,
+                # and this batch's transfer and compute queue behind the
+                # previous batch's compute.  The loop blocks later, in
+                # _resolve's host sync.
+                with TraceAnnotation("serve.put"):
+                    qdev = jax.device_put(qmat)
+                found, rank = self.pack.find(qdev)
+            if rngs:
+                # Ranges ride their own [lo block | hi block] matrix with
+                # an independent capacity class (range traffic is usually
+                # far sparser than point traffic — padding one to the
+                # other's width would double the wasted lanes).
+                rcounts = [0] * self.pack.n_tenants
+                for r in rngs:
+                    t = r.tenant
+                    n = r.keys.shape[1]
+                    rplan.append((r, t, rcounts[t], rcounts[t] + n))
+                    rcounts[t] += n
+                rcap = capacity_class(max(rcounts), self.config.batch_floor)
+                rcap = max(rcap, self.pack.n_shards)
+                rmat = np.zeros((self.pack.n_tenants, 2 * rcap), np.float64)
+                for r, t, a, b in rplan:
+                    rmat[t, a:b] = r.keys[0]
+                    rmat[t, rcap + a:rcap + b] = r.keys[1]
+                rlive = sum(rcounts)
+                self.stats.ranges += rlive
+                self.stats.padded_slots += rmat.size - 2 * rlive
+                self.stats.qcaps.add(rcap)
+                with TraceAnnotation("serve.put"):
+                    rdev = jax.device_put(rmat)
+                rlo, rhi = self.pack.find_range(rdev)
         return _InFlight(found, rank, plan, rlo, rhi, rplan)
 
+    @partial(annotate_function, name="serve.resolve")
     def _resolve(self, inf: _InFlight) -> None:
+        # done_at is stamped before the host sync: a latency taken from it
+        # ends where the batch's resolution starts, which is up to one
+        # device period (``serve.device_wait``) before its answers reach
+        # the host.
         now = self.clock()
         if inf.plan:
-            # sync: ok(the one host sync per batch: point results resolve)
-            found = np.asarray(inf.found)
-            rank = np.asarray(inf.rank)  # sync: ok(rides the found sync)
+            with TraceAnnotation("serve.device_wait"):
+                # sync: ok(the one host sync per batch: point results resolve)
+                found = np.asarray(inf.found)
+                rank = np.asarray(inf.rank)  # sync: ok(rides the found sync)
             for req, t, a, b in inf.plan:
                 req.found = found[t, a:b]
                 req.rank = rank[t, a:b]
                 req.done_at = now
                 req._event.set()
         if inf.rplan:
-            # sync: ok(range leg of the same batch resolution point)
-            rlo = np.asarray(inf.rank_lo)
-            rhi = np.asarray(inf.rank_hi)  # sync: ok(rides the rlo sync)
+            with TraceAnnotation("serve.device_wait"):
+                # sync: ok(range leg of the same batch resolution point)
+                rlo = np.asarray(inf.rank_lo)
+                rhi = np.asarray(inf.rank_hi)  # sync: ok(rides the rlo sync)
             for req, t, a, b in inf.rplan:
                 req.rank_lo = rlo[t, a:b]
                 req.rank_hi = rhi[t, a:b]
                 req.done_at = now
                 req._event.set()
+        inf.span.__exit__(None, None, None)
 
     def _fail(self, batch: list, err: Exception) -> None:
         for req in batch:
@@ -636,6 +723,7 @@ class BatchingFrontend:
                 req.done_at = self.clock()
                 req._event.set()
 
+    @partial(annotate_function, name="serve.maintain")
     def _maintain(self) -> None:
         """Idle-window drift maintenance, run on the dispatcher thread
         between batches when the queue has drained: one pool hot-swap pass
@@ -656,7 +744,7 @@ class BatchingFrontend:
         for t in self.pack.tenants:
             swap = getattr(t, "maybe_swap", None)
             if swap is not None:
-                self.stats.swaps += swap()
+                swap()
 
     def _loop(self) -> None:
         while True:

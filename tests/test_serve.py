@@ -162,12 +162,16 @@ def test_frontend_pads_to_capacity_classes():
     cfg = ServeConfig(latency_budget_s=1e-3, batch_floor=128)
     with BatchingFrontend(tenants, config=cfg) as fe:
         fe.warmup((1, 200))
-        for sz in (1, 3, 127, 128, 129, 200):
+        sizes = (1, 3, 127, 128, 129, 200)
+        for sz in sizes:
             _check(fe, live, 0, rng.choice(live[0], sz), f"sz={sz}")
         assert fe.stats.qcaps <= {128, 256}, fe.stats.qcaps
         for c in fe.stats.qcaps:
             assert c == capacity_class(c, cfg.batch_floor)
-        assert 0.0 < fe.stats.pad_fraction < 1.0
+        # one batch per lookup: two tenant rows of its class, one live
+        slots = sum(2 * capacity_class(sz, cfg.batch_floor) for sz in sizes)
+        assert (fe.stats.queries, fe.stats.ranges) == (sum(sizes), 0)
+        assert fe.stats.padded_slots == slots - sum(sizes)
 
 
 def test_zero_retraces_after_warmup():
