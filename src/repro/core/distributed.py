@@ -1084,15 +1084,16 @@ class ShardedDynamicIndex:
         return [live[int(a):int(b)] for a, b in zip(lo, hi, strict=True)]
 
 
-@functools.lru_cache(maxsize=64)
-def _sharded_dynamic_find_fn(mesh: Mesh, axis: str, *, n_leaves: int,
-                             leaf_kind: str, iters: int, use_kernel: bool,
-                             interpret: bool | None):
-    """Jitted shard_map program for ``ShardedDynamicIndex.find``.  Cached on
-    the static configuration so a mutate/find churn loop only re-traces when
-    a capacity (array shape) actually crosses a power of two."""
-    n_shards = mesh.shape[axis]
-
+def _local_find_fn(*, n_leaves: int, leaf_kind: str, iters: int,
+                   use_kernel: bool, interpret: bool | None):
+    """One shard's two-tier find ``(tables, route_n, base, bdead, bpsum,
+    dk, ddead, dpsum, q) -> (found, rank)``, shared by the sharded and the
+    tenant-stacked find programs: the ``dynamic_lookup_pallas`` kernel via
+    ``ops.dynamic_find``, or the jnp two-tier find with the frozen routing
+    scale as a *traced* per-shard scalar (the static-route_n ``_find_jit``
+    cannot serve shards with different build sizes under one shard_map
+    trace).  On the jnp path ``base`` is in either key form
+    (``rmi.like_keys``); ``q`` is f64."""
     if use_kernel:
         from ..kernels import ops as kernel_ops
 
@@ -1103,35 +1104,85 @@ def _sharded_dynamic_find_fn(mesh: Mesh, axis: str, *, n_leaves: int,
                 q, kroot, kmat, kvec, base, bdead, bpsum, dk, ddead, dpsum,
                 n_leaves=n_leaves, route_n=n_leaves, root_kind="linear",
                 leaf_kind=leaf_kind, iters=iters, interpret=interpret)
-    else:
-        from . import updates as updates_mod
+        return local_find
 
-        def local_find(tables, route_n, base, bdead, bpsum, dk, ddead,
-                       dpsum, q):
-            root, leaves, elo, ehi = tables
-            # f64 two-tier find (``updates._find_jit`` semantics) with the
-            # frozen routing scale as a *traced* per-shard scalar — the
-            # static-route_n jit cannot serve shards with different build
-            # sizes under one shard_map trace.  Everything past this
-            # routing line is the shared updates leaf_window /
-            # two_tier_answer pair.
-            b = jnp.clip((rmi_mod.models.linear_predict(root, q)
-                          * n_leaves / route_n).astype(jnp.int32),
-                         0, n_leaves - 1)
-            lo, hi = updates_mod.leaf_window(leaves, elo, ehi, b, q,
-                                             base.shape[0], leaf_kind)
-            found, rank, _ = updates_mod.two_tier_answer(
-                base, bpsum, dk, dpsum, q, lo, hi, iters)
-            return found, rank
+    from . import updates as updates_mod
+
+    def local_find(tables, route_n, base, bdead, bpsum, dk, ddead, dpsum, q):
+        lo, hi = _jnp_window(tables, route_n, base, q, n_leaves, leaf_kind)
+        found, rank, _ = updates_mod.two_tier_answer(
+            base, bpsum, dk, dpsum, q, lo, hi, iters)
+        return found, rank
+    return local_find
+
+
+def _local_range_fn(*, n_leaves: int, leaf_kind: str, iters: int,
+                    use_kernel: bool, interpret: bool | None):
+    """Range sibling of :func:`_local_find_fn`: ``(rank_lo, rank_hi)`` of
+    each endpoint answered as both a lo and a hi endpoint
+    (``ops.range_lookup`` or the jnp two-tier range tail)."""
+    if use_kernel:
+        from ..kernels import ops as kernel_ops
+
+        def local_range(tables, route_n, base, bdead, bpsum, dk, ddead,
+                        dpsum, q):
+            kroot, kmat, kvec = tables
+            return kernel_ops.range_lookup(
+                q, q, kroot, kmat, kvec, base, bdead, bpsum, dk, ddead,
+                dpsum, n_leaves=n_leaves, route_n=n_leaves,
+                root_kind="linear", leaf_kind=leaf_kind, iters=iters,
+                interpret=interpret)
+        return local_range
+
+    from . import updates as updates_mod
+
+    def local_range(tables, route_n, base, bdead, bpsum, dk, ddead, dpsum,
+                    q):
+        lo, hi = _jnp_window(tables, route_n, base, q, n_leaves, leaf_kind)
+        return updates_mod.two_tier_range_answer(
+            base, bpsum, dk, dpsum, q, q, lo, hi, iters)
+    return local_range
+
+
+def _jnp_window(tables, route_n, base, q, n_leaves: int, leaf_kind: str):
+    """The jnp path's f64 routing and leaf window: the root's bucket under
+    the traced routing scale, then the routed leaf's error-bound window
+    (``updates.leaf_window``)."""
+    from . import updates as updates_mod
+
+    root, leaves, elo, ehi = tables
+    b = jnp.clip((rmi_mod.models.linear_predict(root, q)
+                  * n_leaves / route_n).astype(jnp.int32), 0, n_leaves - 1)
+    return updates_mod.leaf_window(leaves, elo, ehi, b, q,
+                                   rmi_mod.tier_size(base), leaf_kind)
+
+
+def _pad_member(base) -> Array:
+    """The f64 key an exchange pad is searched as: the shard's first base
+    key, or 0.0 on an empty shard's all-+inf row, so pads never blow the
+    sparse seam budget.  ``base`` is one shard's row in either key form."""
+    k0 = rmi_mod.key_value(jax.tree.map(lambda a: a[0], base))
+    return jnp.where(jnp.isfinite(k0), k0, 0.0)
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_dynamic_find_fn(mesh: Mesh, axis: str, *, n_leaves: int,
+                             leaf_kind: str, iters: int, use_kernel: bool,
+                             interpret: bool | None):
+    """Jitted shard_map program for ``ShardedDynamicIndex.find``.  Cached on
+    the static configuration so a mutate/find churn loop only re-traces when
+    a capacity (array shape) actually crosses a power of two."""
+    n_shards = mesh.shape[axis]
+    local_find = _local_find_fn(n_leaves=n_leaves, leaf_kind=leaf_kind,
+                                iters=iters, use_kernel=use_kernel,
+                                interpret=interpret)
 
     def shard_fn(splits, offs, route_n, base, bdead, bpsum, dk, ddead,
                  dpsum, tables, q_local):
         def answer(rq, live):
-            # +inf exchange pads mask to a member key (0.0 on an empty
-            # shard's all-+inf block) so they never blow the sparse seam
-            # budget; their answers are forced dead here.
-            member = jnp.where(jnp.isfinite(base[0][0]), base[0][0], 0.0)
-            qm = jnp.where(live, rq, member)
+            # Exchange pads take a member key (_pad_member); their answers
+            # are forced dead here.
+            qm = jnp.where(live, rq, _pad_member(base[0]))
             found, rank = local_find(jax.tree.map(lambda a: a[0], tables),
                                      route_n[0], base[0], bdead[0],
                                      bpsum[0], dk[0], ddead[0], dpsum[0],
@@ -1167,40 +1218,16 @@ def _sharded_dynamic_range_fn(mesh: Mesh, axis: str, *, n_leaves: int,
     single-pass (``dynamic_range_pallas`` with q_lo == q_hi routes each
     endpoint once per key tile)."""
     n_shards = mesh.shape[axis]
-
-    if use_kernel:
-        from ..kernels import ops as kernel_ops
-
-        def local_range(tables, route_n, base, bdead, bpsum, dk, ddead,
-                        dpsum, q):
-            kroot, kmat, kvec = tables
-            return kernel_ops.range_lookup(
-                q, q, kroot, kmat, kvec, base, bdead, bpsum, dk, ddead,
-                dpsum, n_leaves=n_leaves, route_n=n_leaves,
-                root_kind="linear", leaf_kind=leaf_kind, iters=iters,
-                interpret=interpret)
-    else:
-        from . import updates as updates_mod
-
-        def local_range(tables, route_n, base, bdead, bpsum, dk, ddead,
-                        dpsum, q):
-            root, leaves, elo, ehi = tables
-            b = jnp.clip((rmi_mod.models.linear_predict(root, q)
-                          * n_leaves / route_n).astype(jnp.int32),
-                         0, n_leaves - 1)
-            lo, hi = updates_mod.leaf_window(leaves, elo, ehi, b, q,
-                                             base.shape[0], leaf_kind)
-            return updates_mod.two_tier_range_answer(
-                base, bpsum, dk, dpsum, q, q, lo, hi, iters)
+    local_range = _local_range_fn(n_leaves=n_leaves, leaf_kind=leaf_kind,
+                                  iters=iters, use_kernel=use_kernel,
+                                  interpret=interpret)
 
     def shard_fn(splits, offs, route_n, base, bdead, bpsum, dk, ddead,
                  dpsum, tables, q_local):
         def answer(rq, live):
-            # Same +inf exchange-pad masking as the point path: pads take a
-            # member key so they never blow the sparse seam budget, and
-            # their answers are zeroed here.
-            member = jnp.where(jnp.isfinite(base[0][0]), base[0][0], 0.0)
-            qm = jnp.where(live, rq, member)
+            # Same exchange-pad masking as the point path; pad answers are
+            # zeroed here.
+            qm = jnp.where(live, rq, _pad_member(base[0]))
             rlo, rhi = local_range(jax.tree.map(lambda a: a[0], tables),
                                    route_n[0], base[0], bdead[0], bpsum[0],
                                    dk[0], ddead[0], dpsum[0], qm)
@@ -1253,36 +1280,19 @@ def _tenant_stacked_find_fn(mesh: Mesh, axis: str, *, n_tenants: int,
         the kernel path — traced once with static
         ``n_leaves = route_n = max_t L_t``.
 
+    On the jnp path ``base`` may arrive as its ``(hi, lo)`` f32 words
+    (``rmi.split_words``; the pytree structure selects the trace), so that
+    a call on the TPU gathers its probes from the words instead of
+    splitting the whole f64 tier first.
+
     Cached on the static configuration, so after the serve front-end's
     warmup the hot path never retraces: live batch sizes only vary the
     *contents* of the pow2-padded query rows.
     """
     n_shards = mesh.shape[axis]
-
-    if use_kernel:
-        from ..kernels import ops as kernel_ops
-
-        def local_find(tables, route_n, base, bdead, bpsum, dk, ddead,
-                       dpsum, q):
-            kroot, kmat, kvec = tables
-            return kernel_ops.dynamic_find(
-                q, kroot, kmat, kvec, base, bdead, bpsum, dk, ddead, dpsum,
-                n_leaves=n_leaves, route_n=n_leaves, root_kind="linear",
-                leaf_kind=leaf_kind, iters=iters, interpret=interpret)
-    else:
-        from . import updates as updates_mod
-
-        def local_find(tables, route_n, base, bdead, bpsum, dk, ddead,
-                       dpsum, q):
-            root, leaves, elo, ehi = tables
-            b = jnp.clip((rmi_mod.models.linear_predict(root, q)
-                          * n_leaves / route_n).astype(jnp.int32),
-                         0, n_leaves - 1)
-            lo, hi = updates_mod.leaf_window(leaves, elo, ehi, b, q,
-                                             base.shape[0], leaf_kind)
-            found, rank, _ = updates_mod.two_tier_answer(
-                base, bpsum, dk, dpsum, q, lo, hi, iters)
-            return found, rank
+    local_find = _local_find_fn(n_leaves=n_leaves, leaf_kind=leaf_kind,
+                                iters=iters, use_kernel=use_kernel,
+                                interpret=interpret)
 
     def shard_fn(splits, offs, route_n, base, bdead, bpsum, dk, ddead,
                  dpsum, tables, q):
@@ -1290,12 +1300,11 @@ def _tenant_stacked_find_fn(mesh: Mesh, axis: str, *, n_tenants: int,
         founds, ranks = [], []
         for t in range(n_tenants):
             def answer(rq, live, t=t):
-                member = jnp.where(jnp.isfinite(base[t, 0, 0]),
-                                   base[t, 0, 0], 0.0)
-                qm = jnp.where(live, rq, member)
+                bt = jax.tree.map(lambda a: a[t, 0], base)
+                qm = jnp.where(live, rq, _pad_member(bt))
                 found, rank = local_find(
                     jax.tree.map(lambda a: a[t][0], tables),
-                    route_n[t, 0], base[t, 0], bdead[t, 0], bpsum[t, 0],
+                    route_n[t, 0], bt, bdead[t, 0], bpsum[t, 0],
                     dk[t, 0], ddead[t, 0], dpsum[t, 0], qm)
                 rank = jnp.where(live, rank.astype(jnp.int32) + offs[t, 0],
                                  0)
@@ -1331,31 +1340,9 @@ def _tenant_stacked_range_fn(mesh: Mesh, axis: str, *, n_tenants: int,
     matrices.  Same padding/rescale tricks, same zero-retrace contract
     (``TRACE_COUNTS["tenant_range"]``)."""
     n_shards = mesh.shape[axis]
-
-    if use_kernel:
-        from ..kernels import ops as kernel_ops
-
-        def local_range(tables, route_n, base, bdead, bpsum, dk, ddead,
-                        dpsum, q):
-            kroot, kmat, kvec = tables
-            return kernel_ops.range_lookup(
-                q, q, kroot, kmat, kvec, base, bdead, bpsum, dk, ddead,
-                dpsum, n_leaves=n_leaves, route_n=n_leaves,
-                root_kind="linear", leaf_kind=leaf_kind, iters=iters,
-                interpret=interpret)
-    else:
-        from . import updates as updates_mod
-
-        def local_range(tables, route_n, base, bdead, bpsum, dk, ddead,
-                        dpsum, q):
-            root, leaves, elo, ehi = tables
-            b = jnp.clip((rmi_mod.models.linear_predict(root, q)
-                          * n_leaves / route_n).astype(jnp.int32),
-                         0, n_leaves - 1)
-            lo, hi = updates_mod.leaf_window(leaves, elo, ehi, b, q,
-                                             base.shape[0], leaf_kind)
-            return updates_mod.two_tier_range_answer(
-                base, bpsum, dk, dpsum, q, q, lo, hi, iters)
+    local_range = _local_range_fn(n_leaves=n_leaves, leaf_kind=leaf_kind,
+                                  iters=iters, use_kernel=use_kernel,
+                                  interpret=interpret)
 
     def shard_fn(splits, offs, route_n, base, bdead, bpsum, dk, ddead,
                  dpsum, tables, q):
@@ -1363,12 +1350,11 @@ def _tenant_stacked_range_fn(mesh: Mesh, axis: str, *, n_tenants: int,
         rlos, rhis = [], []
         for t in range(n_tenants):
             def answer(rq, live, t=t):
-                member = jnp.where(jnp.isfinite(base[t, 0, 0]),
-                                   base[t, 0, 0], 0.0)
-                qm = jnp.where(live, rq, member)
+                bt = jax.tree.map(lambda a: a[t, 0], base)
+                qm = jnp.where(live, rq, _pad_member(bt))
                 rlo, rhi = local_range(
                     jax.tree.map(lambda a: a[t][0], tables),
-                    route_n[t, 0], base[t, 0], bdead[t, 0], bpsum[t, 0],
+                    route_n[t, 0], bt, bdead[t, 0], bpsum[t, 0],
                     dk[t, 0], ddead[t, 0], dpsum[t, 0], qm)
                 rlo = jnp.where(live, rlo.astype(jnp.int32) + offs[t, 0], 0)
                 rhi = jnp.where(live, rhi.astype(jnp.int32) + offs[t, 0], 0)

@@ -645,6 +645,67 @@ def rmi_lookup(root_kind: str, root, leaf_kind: str, leaves, err_lo, err_hi,
     return verified_search(keys, queries, lo, hi, iters=iters)
 
 
+# ---------------------------------------------------------------------------
+# Key forms.  A sorted tier is searched in one of two forms: f64 keys, or
+# ``(hi, lo)`` f32 words with hi = f32(x) and lo = f32(x - hi) — the split
+# XLA:TPU's f64 emulation makes of every f64 operand on each call.  Holding
+# a tier as its words lets a program gather two words per probe instead of
+# splitting the whole tier first.  Words compare lexicographically, which
+# orders keys exactly when each key is hi + lo exactly (48 significant
+# bits); an infinite hi gets lo = 0, so +inf pads stay comparable.
+# ---------------------------------------------------------------------------
+def split_words(x: Array) -> tuple:
+    """``(hi, lo)`` f32 words of f64 ``x``."""
+    hi = x.astype(jnp.float32)
+    lo = jnp.where(jnp.isfinite(hi), (x - hi.astype(x.dtype)), 0.0)
+    return hi, lo.astype(jnp.float32)
+
+
+def like_keys(keys, queries: Array):
+    """f64 ``queries`` in the form of ``keys``: as they are for f64 keys,
+    their words for a tier held as words."""
+    return split_words(queries) if isinstance(keys, tuple) else queries
+
+
+def key_value(keys) -> Array:
+    """The f64 value of keys in either form (hi + lo for words)."""
+    if isinstance(keys, tuple):
+        return keys[0].astype(jnp.float64) + keys[1].astype(jnp.float64)
+    return keys
+
+
+def tier_size(keys) -> int:
+    return jax.tree.leaves(keys)[0].shape[0]
+
+
+def _at(keys, i: Array):
+    return jax.tree.map(lambda a: a[i], keys)
+
+
+def _lt(a, b) -> Array:
+    if isinstance(a, tuple):
+        return (a[0] < b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+    return a < b
+
+
+def _ge(a, b) -> Array:
+    return ~_lt(a, b) if isinstance(a, tuple) else a >= b
+
+
+def _le(a, b) -> Array:
+    return ~_lt(b, a) if isinstance(a, tuple) else a <= b
+
+
+def searchsorted_right(keys, queries) -> Array:
+    """First position with ``keys[p] > q`` over the whole tier, ``queries``
+    in the form of ``keys`` (:func:`like_keys`)."""
+    if isinstance(keys, tuple):
+        z = jnp.zeros_like(queries[0], jnp.int32)
+        return bounded_search(keys, queries, z, z + tier_size(keys),
+                              side="right")
+    return jnp.searchsorted(keys, queries, side="right").astype(jnp.int32)
+
+
 @functools.partial(jax.jit, static_argnames=("iters",))
 def verified_search(keys: Array, queries: Array, lo: Array, hi: Array,
                     iters: int | None = None) -> Array:
@@ -654,12 +715,13 @@ def verified_search(keys: Array, queries: Array, lo: Array, hi: Array,
     clamped ``iters`` a query in a sentinel full-array window cannot converge
     in depth). Verify the left-boundary invariant and re-search the full
     array at full depth for the (rare) violations — total lookups stay sound
-    for any query distribution."""
-    n = keys.shape[0]
+    for any query distribution.  ``keys`` and ``queries`` are in either
+    key form, both in the same one."""
+    n = tier_size(keys)
     r = bounded_search(keys, queries, lo, hi, iters=iters)
     rc = jnp.clip(r, 0, n - 1)
-    valid = ((r == 0) | (keys[jnp.clip(r - 1, 0, n - 1)] < queries)) & \
-            ((r == n) | (keys[rc] >= queries))
+    valid = ((r == 0) | _lt(_at(keys, jnp.clip(r - 1, 0, n - 1)), queries)) \
+        & ((r == n) | _ge(_at(keys, rc), queries))
 
     def _fallback(_):
         full = bounded_search(keys, queries, jnp.zeros_like(lo),
@@ -669,23 +731,26 @@ def verified_search(keys: Array, queries: Array, lo: Array, hi: Array,
     return jax.lax.cond(jnp.all(valid), lambda _: r, _fallback, None)
 
 
-@functools.partial(jax.jit, static_argnames=("iters",))
+@functools.partial(jax.jit, static_argnames=("iters", "side"))
 def bounded_search(keys: Array, queries: Array, lo: Array, hi: Array,
-                   iters: int | None = None) -> Array:
+                   iters: int | None = None, side: str = "left") -> Array:
     """Branchless binary search of each query in keys[lo:hi] (left boundary:
-    first position with keys[p] >= q). Fixed iteration count so it vectorizes
-    with no data-dependent control flow; ``iters`` defaults to the full
-    ceil(log2 n) + 1 and can be clamped to the caller's window bound."""
-    n = keys.shape[0]
+    first position with keys[p] >= q; ``side="right"``: first with
+    keys[p] > q). Fixed iteration count so it vectorizes with no
+    data-dependent control flow; ``iters`` defaults to the full
+    ceil(log2 n) + 1 and can be clamped to the caller's window bound.
+    ``keys`` and ``queries`` are in either key form, both in the same one."""
+    n = tier_size(keys)
     if iters is None:
         import math as _math
         iters = _math.ceil(_math.log2(max(n, 2))) + 1
+    before = _lt if side == "left" else _le
 
     def body(_, lh):
         lo, hi = lh
         active = hi - lo > 0
         mid = (lo + hi) // 2
-        below = keys[jnp.clip(mid, 0, n - 1)] < queries
+        below = before(_at(keys, jnp.clip(mid, 0, n - 1)), queries)
         new_lo = jnp.where(below, mid + 1, lo)
         new_hi = jnp.where(below, hi, mid)
         return (jnp.where(active, new_lo, lo), jnp.where(active, new_hi, hi))

@@ -322,9 +322,11 @@ def two_tier_answer(base_keys, base_psum, dk, dpsum, q, lo, hi, iters: int):
     search, then the tombstone-mask / live-rank algebra.  A hit is any
     *live* entry in the equal-key run [pos, right): count live slots via
     the tombstone prefix sums (robust to partially tombstoned duplicate
-    runs).  Returns (found, rank, base_pos)."""
-    pos = rmi_mod.verified_search(base_keys, q, lo, hi, iters=iters)
-    bhi = jnp.searchsorted(base_keys, q, side="right").astype(jnp.int32)
+    runs).  ``base_keys`` is in either key form (``rmi.like_keys``); ``q``
+    is f64.  Returns (found, rank, base_pos)."""
+    bq = rmi_mod.like_keys(base_keys, q)
+    pos = rmi_mod.verified_search(base_keys, bq, lo, hi, iters=iters)
+    bhi = rmi_mod.searchsorted_right(base_keys, bq)
     base_hit = (bhi - pos) > (base_psum[bhi] - base_psum[pos])
     dpos = jnp.searchsorted(dk, q, side="left").astype(jnp.int32)
     dhi = jnp.searchsorted(dk, q, side="right").astype(jnp.int32)
@@ -358,9 +360,11 @@ def two_tier_range_answer(base_keys, base_psum, dk, dpsum, q_lo, q_hi,
     is clamped to rank_lo, so degenerate ranges (q_lo > q_hi, a tombstoned
     singleton, fully out-of-range windows) return an empty [lo, lo) span
     rather than a negative width.  ``lo``/``hi`` are q_lo's error-bound
-    window.  Returns (rank_lo, rank_hi)."""
-    blo = rmi_mod.verified_search(base_keys, q_lo, lo, hi, iters=iters)
-    bhi = jnp.searchsorted(base_keys, q_hi, side="right").astype(jnp.int32)
+    window.  ``base_keys`` is in either key form, as in
+    :func:`two_tier_answer`.  Returns (rank_lo, rank_hi)."""
+    like = functools.partial(rmi_mod.like_keys, base_keys)
+    blo = rmi_mod.verified_search(base_keys, like(q_lo), lo, hi, iters=iters)
+    bhi = rmi_mod.searchsorted_right(base_keys, like(q_hi))
     dlo = jnp.searchsorted(dk, q_lo, side="left").astype(jnp.int32)
     dhi = jnp.searchsorted(dk, q_hi, side="right").astype(jnp.int32)
     rank_lo = (blo - base_psum[blo]) + (dlo - dpsum[dlo])

@@ -277,7 +277,7 @@ def _dynamic_lookup_jit(queries, root, mat, vec, keys, base_dead, base_psum,
     df = _lookup.pad_delta(delta_keys)
     nd = df.shape[0]
     dhi = jnp.searchsorted(df, qf, side="right").astype(dpos.dtype)
-    dpsum = jnp.pad(delta_psum, (0, nd + 1 - delta_psum.shape[0]),
+    dpsum = jnp.pad(delta_psum, (0, max(nd + 1 - delta_psum.shape[0], 0)),
                     mode="edge")
     delta_hit = (dhi - dpos) > (dpsum[dhi] - dpsum[dpos])
     # Live rank across both tiers: positions minus tombstones left of them.
@@ -338,7 +338,7 @@ def _range_lookup_jit(q_lo, q_hi, root, mat, vec, keys, base_psum,
     blo = _seam_fix(blo, kf, qlf, seam_budget)
     bhi = _seam_fix(bhi, kf, qhf, seam_budget, right=True)
     nd = _lookup.pad_delta(delta_keys).shape[0]
-    dpsum = jnp.pad(delta_psum, (0, nd + 1 - delta_psum.shape[0]),
+    dpsum = jnp.pad(delta_psum, (0, max(nd + 1 - delta_psum.shape[0], 0)),
                     mode="edge")
     rank_lo = (blo - base_psum[blo]) + (dlo - dpsum[dlo])
     rank_hi = (bhi - base_psum[bhi]) + (dhi - dpsum[dhi])

@@ -88,6 +88,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation, annotate_function
 
 from ..core import distributed as dist_mod
+from ..core import rmi as rmi_mod
 from ..core.paths import resolve_path
 from ..kernels.lookup import capacity_class, pad_packed_leaves
 
@@ -256,16 +257,49 @@ class AdaptiveBatcher:
         return batch
 
 
+def _psum_len(cap: int) -> int:
+    """Stacked length of a tier's prefix sums: ``cap + 1`` rounded up to a
+    multiple of 1024, the tile the TPU lays a gathered 1-D int32 operand
+    out in, so no dispatch copies them into that layout.  Positions past
+    ``cap`` are never read."""
+    return -(-(cap + 1) // 1024) * 1024
+
+
+@jax.jit
+def _stack_words(bases: list) -> tuple:
+    """Stacked f64 base rows as their ``(hi, lo)`` f32 words
+    (``rmi.split_words``)."""
+    return rmi_mod.split_words(jnp.stack(bases))
+
+
+@partial(jax.jit, static_argnames="n")
+def _stack_psums(psums: list, n: int) -> Array:
+    """Stacked prefix-sum rows, each edge-padded to length ``n`` in the
+    same program, so no padded copy of a row is made on the side."""
+    return jnp.stack([jnp.pad(p, ((0, 0), (0, n - p.shape[-1])),
+                              mode="edge") for p in psums])
+
+
 class TenantPack:
     """N tenants' stacked per-shard state, padded to cross-tenant max
     capacity classes and maintained incrementally: ``find`` refreshes only
     the rows of tenants whose own slice cache changed (donated row
     scatters), and re-assembles cold only when a cross-tenant capacity
-    class crosses a pow2."""
+    class crosses a pow2.
+
+    The operands are held in the form the stacked programs read.  Prefix
+    sums pad to :func:`_psum_len`.  On the jnp path where XLA emulates f64
+    (the TPU), the base tier is held as its ``(hi, lo)`` f32 words
+    (``rmi.split_words``), split once per assembled or restacked row
+    (``base_splits`` counts them); otherwise every dispatch would split
+    the whole base to gather a few probes from it.  Elsewhere (the CPU's
+    native f64, the kernel path) the base stays f64.  ``_words`` forces
+    the form, for tests."""
 
     def __init__(self, tenants: list, *, path: str = "auto",
                  use_kernel: bool | None = None,
-                 interpret: bool | None = None):
+                 interpret: bool | None = None,
+                 _words: bool | None = None):
         if not tenants:
             raise ValueError("TenantPack needs at least one tenant")
         mesh, axis = tenants[0].mesh, tenants[0].axis
@@ -282,6 +316,9 @@ class TenantPack:
         self.use_kernel = bool(use_kernel)
         self.interpret = interpret if interpret is None else bool(interpret)
         self.leaf_kind = kinds.pop()
+        if _words is None:
+            _words = mesh.devices.flat[0].platform == "tpu"
+        self.words = _words and not self.use_kernel
         self.n_leaves = max(t.n_leaves for t in tenants)
         # Common packed lane count: tenants re-pad to the widest tenant's
         # 128-multiple (pack_leaves layout).
@@ -290,6 +327,7 @@ class TenantPack:
         self._geom = None
         self._fps: list | None = None     # per-tenant identity fingerprints
         self.pack_rows = 0                # tenant rows rewritten in place
+        self.base_splits = 0              # tenant base rows split to words
 
     @property
     def n_tenants(self) -> int:
@@ -312,7 +350,8 @@ class TenantPack:
 
     def _tenant_row(self, t, st: dict, bcap: int, dcap: int) -> dict:
         """One tenant's (S, ...) slice set padded to the cross-tenant
-        geometry — the unit of incremental tenant restacking."""
+        geometry (prefix sums are padded as they are stacked,
+        :meth:`_stack`) — the unit of incremental tenant restacking."""
         L, lt = self.n_leaves, t.n_leaves
         padv = lambda a, c, v: a if a.shape[1] == c else jnp.pad(
             a, ((0, 0), (0, c - a.shape[1])), constant_values=v)
@@ -329,10 +368,10 @@ class TenantPack:
             route_n=st["route_n"] * (jnp.float64(L) / jnp.float64(lt)),
             base=padv(st["base"], bcap, jnp.inf),
             bdead=padv(st["bdead"], bcap, False),
-            bpsum=pade(st["bpsum"], bcap + 1),
+            bpsum=st["bpsum"],
             dk=padv(st["dk"], dcap, jnp.inf),
             ddead=padv(st["ddead"], dcap, False),
-            dpsum=pade(st["dpsum"], dcap + 1),
+            dpsum=st["dpsum"],
             root=st["root"],
             leaves=jax.tree.map(lambda a: pade(a, L), st["leaves"]),
             err_lo=pade(st["err_lo"], L),
@@ -345,6 +384,24 @@ class TenantPack:
 
     _STACK_KEYS = ("splits", "offs", "route_n", "base", "bdead", "bpsum",
                    "dk", "ddead", "dpsum", "err_lo", "err_hi")
+    _MODEL_KEYS = ("root", "leaves")
+
+    def _kernel_keys(self) -> tuple:
+        return ("kroot", "kmat", "kvec") if self.use_kernel else ()
+
+    def _stack(self, k: str, rows: list, geom: tuple):
+        """Tenant rows' ``k`` stacked along a leading tenant axis, leaf by
+        leaf; prefix sums padded to :func:`_psum_len` of the capacity
+        classes ``geom = (bcap, dcap)``; the base, in the words form, as
+        its words (:func:`_stack_words`)."""
+        col = [r[k] for r in rows]
+        if k in ("bpsum", "dpsum"):
+            cap = geom[0] if k == "bpsum" else geom[1]
+            return _stack_psums(col, _psum_len(cap))
+        if k == "base" and self.words:
+            self.base_splits += len(col)
+            return _stack_words(col)
+        return jax.tree.map(lambda *a: jnp.stack(a), *col)
 
     @partial(annotate_function, name="serve.refresh")
     def _refresh(self) -> dict:
@@ -359,18 +416,13 @@ class TenantPack:
         if self._st is None or geom != self._geom:
             rows = [self._tenant_row(t, st, bcap, dcap)
                     for t, st in zip(self.tenants, sts, strict=True)]
-            stack = lambda k: jnp.stack([r[k] for r in rows])
+            stack = lambda k: self._stack(k, rows, geom)
             lay = self.tenants[0].shard_sharding(lead=1)
             self._st = {k: stack(k) if k == "splits"
                         else jax.device_put(stack(k), lay)
                         for k in self._STACK_KEYS}
-            tmap = lambda k: jax.tree.map(lambda *a: jnp.stack(a),
-                                          *[r[k] for r in rows])
-            self._st["root"] = tmap("root")
-            self._st["leaves"] = tmap("leaves")
-            if self.use_kernel:
-                for k in ("kroot", "kmat", "kvec"):
-                    self._st[k] = stack(k)
+            for k in self._MODEL_KEYS + self._kernel_keys():
+                self._st[k] = stack(k)
             self._geom = geom
         else:
             stale = [i for i, fp in enumerate(fps)
@@ -381,17 +433,12 @@ class TenantPack:
             for i in stale:
                 row = self._tenant_row(self.tenants[i], sts[i], bcap, dcap)
                 idx = jnp.asarray([i])
-                for k in self._STACK_KEYS + (
-                        ("kroot", "kmat", "kvec") if self.use_kernel
-                        else ()):
-                    self._st[k] = dist_mod.scatter_rows_donated(
-                        self._st[k], idx, row[k][None])
                 scat = lambda dst, r, idx=idx: \
-                    dist_mod.scatter_rows_donated(dst, idx, r[None])
-                self._st["root"] = jax.tree.map(scat, self._st["root"],
-                                                row["root"])
-                self._st["leaves"] = jax.tree.map(scat, self._st["leaves"],
-                                                  row["leaves"])
+                    dist_mod.scatter_rows_donated(dst, idx, r)
+                for k in self._STACK_KEYS + self._MODEL_KEYS + \
+                        self._kernel_keys():
+                    self._st[k] = jax.tree.map(scat, self._st[k],
+                                               self._stack(k, [row], geom))
                 self.pack_rows += 1
         self._fps = fps
         self._st["iters"] = max(st["iters"] for st in sts)

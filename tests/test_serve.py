@@ -317,6 +317,107 @@ def test_tenant_pack_bit_exact_single_device():
                                           err_msg=f"oracle t={t} uk={uk}")
 
 
+# ------------------------------------------------ base held as f32 words --
+def _words_tenants(seed: int = 29):
+    """Two tenants of different sizes on the 1-device mesh, keys exact in 48
+    bits (integers under 2^44, some plus a half) in duplicate runs, with
+    tombstones over partial and whole runs and a few buffered inserts.
+    Returns (tenants, live keys per tenant, probe keys per tenant)."""
+    rng = np.random.default_rng(seed)
+    mesh = jax.make_mesh((1,), ("data",))
+    tenants, live, probes = [], [], []
+    for n, nl in ((3000, 64), (700, 16)):
+        k = np.unique(rng.integers(1, 1 << 44, n)).astype(np.float64)
+        k[::7] += 0.5
+        keys = np.repeat(k, rng.integers(1, 4, k.size))
+        t = dist_mod.ShardedDynamicIndex.build(jnp.asarray(keys), mesh,
+                                               n_leaves=nl, eps=0.7)
+        cur = list(keys)
+        dead = rng.choice(k, 60, replace=False)
+        for batch in (dead, dead[:30], dead[:30]):   # partial, then whole
+            t.delete_batch(batch)
+            for d in batch:
+                if d in cur:
+                    cur.remove(d)
+        fresh = np.setdiff1d(np.unique(rng.integers(1, 1 << 44, 24)), k)
+        t.insert_batch(fresh.astype(np.float64))
+        cur = np.sort(np.concatenate([cur, fresh]))
+        tenants.append(t)
+        live.append(cur)
+        probes.append(np.concatenate([
+            rng.choice(cur, 150), dead, dead + 0.5, dead - 1, fresh,
+            [0.0, -1e30, 1e30, cur[0] - 0.5, cur[-1] + 0.5, cur[-1] * 2]]))
+    return tenants, live, probes
+
+
+def _qmat(probes, width: int, rng):
+    return np.stack([rng.permutation(np.resize(p, width)) for p in probes])
+
+
+@pytest.mark.parametrize("kind", ["find", "range"])
+def test_tenant_pack_words_match_f64(kind):
+    """The stacked jnp dispatch answers bit for bit the same with the base
+    held as its (hi, lo) f32 words as with f64 keys, and both match the
+    sorted-array oracle: duplicate runs, tombstoned runs, +inf pads (the
+    smaller tenant pads to the larger one's capacity), out-of-range keys."""
+    tenants, live, probes = _words_tenants()
+    rng = np.random.default_rng(5)
+    f64 = TenantPack(tenants, _words=False)
+    words = TenantPack(tenants, _words=True)
+    assert words.words and not f64.words
+    base = words._refresh()["base"]
+    assert [b.dtype for b in base] == [jnp.float32, jnp.float32]
+    assert capacity_class(live[1].size) < base[0].shape[-1]
+    q = _qmat(probes, 512, rng)
+    if kind == "find":
+        a, b = f64.find(jnp.asarray(q)), words.find(jnp.asarray(q))
+        want = [[np.searchsorted(live[t], q[t], side="right") >
+                 np.searchsorted(live[t], q[t]) for t in range(2)],
+                [np.searchsorted(live[t], q[t]) for t in range(2)]]
+    else:
+        hi = q + rng.choice([-3.0, 0.0, 0.5, 1e9, 1e12], q.shape)
+        rmat = jnp.asarray(np.concatenate([q, hi], axis=1))
+        a, b = f64.find_range(rmat), words.find_range(rmat)
+        lo = [np.searchsorted(live[t], q[t]) for t in range(2)]
+        want = [lo, [np.maximum(np.searchsorted(live[t], hi[t], "right"),
+                                lo[t]) for t in range(2)]]
+    for x, y, w in zip(a, b, want, strict=True):
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(x))
+        np.testing.assert_array_equal(np.asarray(y), np.stack(w))
+
+
+def test_tenant_pack_splits_base_once_per_row():
+    """The words form splits each tenant's base once at cold assembly and
+    again only when that tenant's row goes stale, never per dispatch; the
+    f64 form (the CPU mesh's own choice) splits nothing."""
+    tenants, live, probes = _words_tenants()
+    rng = np.random.default_rng(6)
+    q = jnp.asarray(_qmat(probes, 256, rng))
+    rmat = jnp.concatenate([q, q + 1.0], axis=1)
+    pack = TenantPack(tenants, _words=True)
+    auto = TenantPack(tenants)
+    assert not auto.words
+    assert pack.base_splits == 0
+    for _ in range(3):
+        pack.find(q)
+        pack.find_range(rmat)
+        auto.find(q)
+    assert pack.base_splits == 2 and auto.base_splits == 0
+    fresh = np.asarray([live[1][0] + 0.25, live[1][-1] + 3.0])
+    tenants[1].insert_batch(fresh)
+    live[1] = np.sort(np.concatenate([live[1], fresh]))
+    found, rank = pack.find(q)
+    assert pack.base_splits == 3
+    for _ in range(3):
+        found, rank = pack.find(q)
+        pack.find_range(rmat)
+    assert pack.base_splits == 3
+    qn = np.asarray(q)
+    np.testing.assert_array_equal(
+        np.asarray(rank), np.stack([np.searchsorted(live[t], qn[t])
+                                    for t in range(2)]))
+
+
 # --------------------------------------------------------- multi-device ---
 _SCRIPT = r"""
 import os
@@ -352,9 +453,10 @@ qmat = np.stack([
          if tenants[t].n_shards > 1 else np.zeros(0),
          [0.0, 1e30, live[t][0] / 2, live[t][-1] * 2]]))[:qcap]
     for t in range(2)])
-for uk in (False, True):
+ranges = []
+for uk, words in ((False, False), (False, True), (True, None)):
     pack = TenantPack(tenants, use_kernel=uk,
-                      interpret=True if uk else None)
+                      interpret=True if uk else None, _words=words)
     f, r = pack.find(jnp.asarray(qmat))
     f, r = np.asarray(f), np.asarray(r)
     for t, idx in enumerate(tenants):
@@ -366,6 +468,11 @@ for uk in (False, True):
         np.testing.assert_array_equal(
             r[t], np.searchsorted(live[t], qmat[t], side="left"),
             err_msg="oracle t=%%d uk=%%s" %% (t, uk))
+    if not uk:
+        rmat = jnp.asarray(np.concatenate([qmat, qmat * 1.01], axis=1))
+        ranges.append([np.asarray(a) for a in pack.find_range(rmat)])
+for a, b in zip(*ranges):                # words form == f64 form
+    np.testing.assert_array_equal(a, b, err_msg="range words")
 
 # ---- frontend end-to-end: zero retraces, then interleaved churn --------
 def check(fe, tid, q, tag):

@@ -20,6 +20,7 @@ the topology fails them.
 import functools
 import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,7 @@ import repro  # noqa: F401  (x64 on)
 from repro.core import rmi
 from repro.core import updates as upd
 from repro.kernels import hist, ksdist, linfit, lookup
+from repro.serve.frontend import _psum_len
 
 S = 1 << 27          # the one-chip smoke's base-tier capacity class
 L = 1 << 16          # its leaves per shard
@@ -133,11 +135,70 @@ def test_sharded_dispatch_compiles(topo, kind):
     q = _sds(sh, (1, 512 * (2 if kind == "range" else 1)), jnp.float64)
     args = (_sds(sh, (1, 0), jnp.float64), sds((), jnp.int32),
             sds((), jnp.float64), sds((S,), jnp.float64),
-            sds((S,), jnp.bool_), sds((S + 1,), jnp.int32),
+            sds((S,), jnp.bool_), sds((_psum_len(S),), jnp.int32),
             sds((ND,), jnp.float64), sds((ND,), jnp.bool_),
-            sds((ND + 1,), jnp.int32), tables, q)
+            sds((_psum_len(ND),), jnp.int32), tables, q)
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# An instruction's name, result type and opcode in compiled HLO text; a
+# tuple-typed result (a while loop's carry, a tuple) moves no data.
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w-]+)\(")
+_NO_DATA = {"parameter", "get-tuple-element", "bitcast", "tuple"}
+
+
+def _elements(dims: str) -> int:
+    return int(np.prod([int(d) for d in dims.split(",") if d]))
+
+
+@pytest.mark.parametrize("kind", ["find", "range"])
+def test_stacked_jnp_dispatch_touches_no_whole_base(topo, kind):
+    """The front-end's stacked jnp dispatch, with its operands in the form
+    ``TenantPack`` holds them on a TPU (the base tier as its (hi, lo) f32
+    words, prefix sums padded to ``_psum_len``), on one chip at S = 2^27:
+    a call gathers from the base and its prefix sums and converts neither.
+    No instruction but a parameter, a bitcast or a tuple access yields a
+    base-sized array, and no split of an f64 (``X64Split*``), ``reduce``
+    or ``copy`` reads one.  Held as f64 (``X64SplitHigh``/``Low`` of the
+    whole base) or with S + 1 prefix sums (a ``reduce`` that relayouts
+    them), each call would rewrite the whole tier."""
+    from repro.core import distributed as dist
+
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("data",))
+    make = dist._tenant_stacked_range_fn if kind == "range" \
+        else dist._tenant_stacked_find_fn
+    fn = make(mesh, "data", n_tenants=1, n_leaves=L, leaf_kind="linear",
+              iters=14, use_kernel=False, interpret=None)
+    sh = dist.NamedSharding(mesh, dist.P())
+    sds = lambda shape, dtype: _sds(sh, (1, 1) + shape, dtype)
+    lin = lambda shape: rmi.models.LinearParams(sds(shape, jnp.float64),
+                                                sds(shape, jnp.float64))
+    tables = (lin(()), lin((L,)), sds((L,), jnp.float64),
+              sds((L,), jnp.float64))
+    q = _sds(sh, (1, 512 * (2 if kind == "range" else 1)), jnp.float64)
+    args = (_sds(sh, (1, 0), jnp.float64), sds((), jnp.int32),
+            sds((), jnp.float64),
+            (sds((S,), jnp.float32), sds((S,), jnp.float32)),
+            sds((S,), jnp.bool_), sds((_psum_len(S),), jnp.int32),
+            sds((ND,), jnp.float64), sds((ND,), jnp.bool_),
+            sds((_psum_len(ND),), jnp.int32), tables, q)
+    text = fn.lower(*args).compile().as_text()
+    made, read = [], []
+    for line in text.splitlines():
+        m = _INSTR.match(line)
+        if m is None:
+            continue
+        name, _, dims, op = m.groups()
+        if _elements(dims) >= S and op not in _NO_DATA:
+            made.append(line.strip()[:200])
+        converts = op in ("reduce", "copy") or "X64Split" in line
+        if converts and any(_elements(d) >= S for d in
+                            re.findall(r"\[([\d,]*)\]", line)):
+            read.append(line.strip()[:200])
+    assert "while" in text          # the bisection is there
+    assert not made, made
+    assert not read, read
 
 
 def _update_programs(leaves, root):
